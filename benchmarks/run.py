@@ -48,6 +48,8 @@ def main() -> None:
                          "bulk build is at least this many times faster "
                          "than incremental at recall within 0.02")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     timestamp = args.timestamp if args.timestamp is not None else time.time()
     failures = []

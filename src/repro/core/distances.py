@@ -24,6 +24,9 @@ import jax
 import jax.numpy as jnp
 
 Array = jax.Array
+# f32 matmuls at full precision: the TPU's default rounds f32 operands to
+# bf16, which would make the exact (flat) scan and the rescore inexact
+HIGHEST = jax.lax.Precision.HIGHEST
 
 #: Registry of metric name -> pairwise fn (queries (Q,D), corpus (N,D)) -> (Q,N)
 _METRICS: Dict[str, Callable[[Array, Array], Array]] = {}
@@ -71,7 +74,7 @@ def pairwise_l2(queries: Array, corpus: Array) -> Array:
     # (Q,N) = q2[:,None] + x2[None,:] - 2 q @ x.T  -- one big matmul.
     qq = l2_norm_sq(q)  # (Q,)
     xx = l2_norm_sq(x)  # (N,)
-    cross = q @ x.T  # (Q,N) on the MXU
+    cross = jnp.matmul(q, x.T, precision=HIGHEST)  # (Q,N) on the MXU
     d = qq[:, None] + xx[None, :] - 2.0 * cross
     return jnp.maximum(d, 0.0)  # clamp fp error
 
@@ -79,7 +82,8 @@ def pairwise_l2(queries: Array, corpus: Array) -> Array:
 @register_metric("dot")
 def pairwise_dot(queries: Array, corpus: Array) -> Array:
     """Negative inner product (so smaller == more similar)."""
-    return -(queries.astype(jnp.float32) @ corpus.astype(jnp.float32).T)
+    return -jnp.matmul(queries.astype(jnp.float32),
+                       corpus.astype(jnp.float32).T, precision=HIGHEST)
 
 
 @register_metric("cosine")

@@ -285,8 +285,7 @@ class QuantixarEngine:
         """Ship the sealed graph to device.  Quantized engines additionally
         ship the code matrix (PQ uint codes / packed BQ uint32 words) so
         layer-0 traversal runs in code domain through the fused beam-gather
-        kernels; the float proxy vectors stay aboard for upper-layer
-        descent."""
+        kernels; the float proxy vectors stay aboard for the entry scan."""
         codes = None
         if self.config.quantization in ("pq", "bq") and self._codes is not None:
             codes = self._codes[: self._packed.n]

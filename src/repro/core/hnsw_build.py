@@ -105,11 +105,10 @@ class PackedHNSW:
     config: HNSWConfig
     vectors: np.ndarray        # (N, D) float32, preprocessed
     adj0: np.ndarray           # (N, M0) int32 global ids, PAD-filled
-    upper_ids: np.ndarray      # (n_upper,) int32: upper-slot -> global id
-    upper_adj: np.ndarray      # (n_upper, L_top, M) int32 *upper-slot* ids
+    upper_ids: np.ndarray      # (n_upper,) int32 layer>=1 members: the
+    #                            search's entry candidates
     levels: np.ndarray         # (N,) int8 node levels
     entry_global: int
-    entry_upper: int
     max_level: int
     # builder observability (mode, batch/cluster/stitch counters); not
     # serialized — checkpoints restore it empty
@@ -128,19 +127,17 @@ class PackedHNSW:
     def state_dict(self) -> Dict[str, np.ndarray]:
         return {
             "vectors": self.vectors, "adj0": self.adj0,
-            "upper_ids": self.upper_ids, "upper_adj": self.upper_adj,
-            "levels": self.levels,
-            "meta": np.array([self.entry_global, self.entry_upper,
-                              self.max_level], dtype=np.int64),
+            "upper_ids": self.upper_ids, "levels": self.levels,
+            "meta": np.array([self.entry_global, self.max_level],
+                             dtype=np.int64),
         }
 
     @classmethod
     def from_state_dict(cls, state, config: HNSWConfig) -> "PackedHNSW":
-        eg, eu, ml = (int(v) for v in state["meta"])
+        eg, ml = (int(v) for v in state["meta"])
         return cls(config=config, vectors=state["vectors"], adj0=state["adj0"],
-                   upper_ids=state["upper_ids"], upper_adj=state["upper_adj"],
-                   levels=state["levels"], entry_global=eg, entry_upper=eu,
-                   max_level=ml)
+                   upper_ids=state["upper_ids"], levels=state["levels"],
+                   entry_global=eg, max_level=ml)
 
 
 # ---------------------------------------------------------------------------
@@ -317,23 +314,14 @@ def _pack(builder: _GraphBuilder) -> PackedHNSW:
         row = nbrs[: cfg.m0]
         adj0[node, : len(row)] = row
 
+    # the upper layers steer insertion only: search enters layer 0 at the
+    # nearest layer>=1 member (hnsw_search._entry_points), so only the
+    # membership is packed
     upper_ids = np.where(builder.levels >= 1)[0].astype(np.int32)
-    slot_of = {int(g): s for s, g in enumerate(upper_ids)}
-    l_top = max(builder.max_level, 1)
-    upper_adj = np.full((max(len(upper_ids), 1), l_top, cfg.M), PAD,
-                        dtype=np.int32)
-    for layer in range(1, builder.max_level + 1):
-        for node, nbrs in builder.adj[layer].items():
-            s = slot_of[node]
-            row = [slot_of[e] for e in nbrs[: cfg.M]]
-            upper_adj[s, layer - 1, : len(row)] = row
-
-    entry_upper = slot_of.get(builder.entry, 0) if len(upper_ids) else 0
     return PackedHNSW(
         config=cfg, vectors=builder.vectors, adj0=adj0,
         upper_ids=upper_ids if len(upper_ids) else np.zeros((1,), np.int32),
-        upper_adj=upper_adj, levels=builder.levels,
-        entry_global=builder.entry, entry_upper=entry_upper,
+        levels=builder.levels, entry_global=builder.entry,
         max_level=builder.max_level)
 
 
@@ -366,10 +354,8 @@ def bulk_build(vectors: np.ndarray, config: HNSWConfig = HNSWConfig(),
                progress: Optional[ProgressFn] = None) -> PackedHNSW:
     """Build the packed structure from an exact kNN graph (one GEMM per chunk).
 
-    Level structure is sampled with the same geometric distribution; layer-l
-    adjacency connects each upper node to its nearest peers *within the same
-    layer's node set* — preserving the hierarchy's coarse-to-fine routing.
-    The base layer applies the Alg-4 diversification heuristic to the kNN
+    Levels are sampled with the same geometric distribution; the layer>=1
+    members are the search's entry candidates.  The base layer applies the Alg-4 diversification heuristic to the kNN
     candidate list (this is what turns a kNN graph into a navigable graph).
     """
     cfg = config
@@ -440,7 +426,8 @@ def bulk_build(vectors: np.ndarray, config: HNSWConfig = HNSWConfig(),
             row = [row[j] for j in np.argsort(ds)[: cfg.m0]]
         adj0[i, : len(row)] = row
 
-    # hierarchy: geometric level sampling, per-layer kNN among layer members
+    # hierarchy: geometric level sampling; the layer>=1 members are the
+    # search's entry candidates (no upper-layer links: search scans them)
     levels = np.minimum((-np.log(np.maximum(rng.random_sample(n), 1e-12))
                          * cfg.mL).astype(np.int64), 127).astype(np.int8)
     max_level = int(levels.max()) if n else 0
@@ -449,37 +436,10 @@ def bulk_build(vectors: np.ndarray, config: HNSWConfig = HNSWConfig(),
         upper_ids = np.array([0], dtype=np.int32)
         levels[0] = 1
         max_level = max(max_level, 1)
-    slot_of = {int(g): s for s, g in enumerate(upper_ids)}
-    l_top = max(max_level, 1)
-    upper_adj = np.full((len(upper_ids), l_top, cfg.M), PAD, dtype=np.int32)
-    for layer in range(1, max_level + 1):
-        members = upper_ids[levels[upper_ids] >= layer]
-        if len(members) <= 1:
-            continue
-        kk = min(max(cfg.M - 2, 1), len(members) - 1)
-        nn = exact_knn(vecs[members], vecs[members], kk + 1,
-                       metric=cfg.metric, chunk=chunk)[:, 1:]
-        # symmetrized kNN + a couple of random member links per node —
-        # upper-layer routing must not fragment on clustered data
-        links = {int(g): set(int(members[j]) for j in nn[row_i])
-                 for row_i, g in enumerate(members)}
-        for row_i, g in enumerate(members):
-            for j in rng.randint(0, len(members), size=2):
-                if int(members[j]) != int(g):
-                    links[int(g)].add(int(members[j]))
-            for nb in list(links[int(g)]):
-                links[nb].add(int(g))
-        for g, nbrs in links.items():
-            s = slot_of[g]
-            row = [slot_of[nb] for nb in list(nbrs)[: cfg.M]]
-            upper_adj[s, layer - 1, : len(row)] = row
-
     top_members = upper_ids[levels[upper_ids] >= max_level]
     entry_global = int(top_members[0]) if len(top_members) else int(upper_ids[0])
     return PackedHNSW(config=cfg, vectors=vecs, adj0=adj0, upper_ids=upper_ids,
-                      upper_adj=upper_adj, levels=levels,
-                      entry_global=entry_global,
-                      entry_upper=slot_of.get(entry_global, 0),
+                      levels=levels, entry_global=entry_global,
                       max_level=max_level)
 
 

@@ -22,11 +22,11 @@ of the seed ``bulk_build`` is lifted to fixed-shape jitted array programs:
   * **Level-wise batched inserts** (`_bulk_level`): nodes are inserted in
     descending-level order; the first batch bootstraps the graph (and all
     upper-layer nodes) from exact kNN, each following batch runs vmapped
-    wide-beam descents (hnsw_search.search — PR 4's fused ``beam_gather``
+    wide-beam searches (hnsw_search.search — the fused ``beam_gather``
     kernels) over the *frozen prefix* graph to collect candidates, plus an
     intra-batch kNN block so batch-mates can link to each other.
   * **Two-phase coarse mode** (`_bulk_coarse`): for cold-start bulk loads
-    the beam descents are replaced by k-means coarse clustering (the
+    the beam searches are replaced by k-means coarse clustering (the
     ``ivf.py``/``pq.py`` machinery) → intra-cluster exact kNN (each node
     sees the union of its two nearest clusters, so boundary nodes get
     cross-cluster candidates) → one global prune + symmetrize → boundary
@@ -34,9 +34,10 @@ of the seed ``bulk_build`` is lifted to fixed-shape jitted array programs:
     searches over the built graph.  Build cost scales ~O(n·k·d) instead of
     the O(n²) brute-force self-join.
 
-Both modes share the level sampling, upper-hierarchy construction and
-connectivity repair, and produce a `PackedHNSW` interchangeable with the
-incremental builder's output.  Mode "auto" picks coarse at
+Both modes share the level sampling (the layer>=1 members are the search's
+entry candidates; no upper-layer links are built) and connectivity repair,
+and produce a `PackedHNSW` interchangeable with the incremental builder's
+output.  Mode "auto" picks coarse at
 ``coarse_threshold`` rows and level-wise below it; corpora too small for
 fixed-shape batching fall back to the numpy reference ``bulk_build``.
 """
@@ -45,6 +46,7 @@ from __future__ import annotations
 
 import functools
 import logging
+import time
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -52,6 +54,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import ops
+from ..kernels.beam_gather import gather_table
+from .flat import flat_search
 from .hnsw_build import (PAD, HNSWConfig, PackedHNSW, ProgressFn, bulk_build,
                          knn_ids_dists, preprocess_vectors)
 from .hnsw_search import HNSWGraph
@@ -66,6 +70,8 @@ PRUNE_CHUNK = 512        # nodes pruned per jitted call (fixed shape)
 MIN_DEVICE_N = 32        # below this the numpy reference builder is used
 STITCH_EF = 64           # beam width cap for cross-cluster stitching
 KMEANS_ITERS = 8
+KNN_BLOCK = 1024         # query rows per device kNN call
+KNN_BUCKET = 4096        # member sets pad to a multiple (bounds recompiles)
 
 
 # ---------------------------------------------------------------------------
@@ -77,6 +83,8 @@ def _prune_batch(corpus: jax.Array, q_ids: jax.Array, cand_ids: jax.Array,
                  cand_d: jax.Array, *, m: int, mode: str,
                  keep_pruned: bool) -> Tuple[jax.Array, jax.Array]:
     """Batched diversification prune: (B, C) candidates -> (B, m) selected.
+
+    ``corpus`` is the gather table (N, 1, Dp) of the preprocessed vectors.
 
     Candidate j survives iff it is closer to the query than to every
     already-selected neighbour (the paper's Alg 4), evaluated as a masked
@@ -283,8 +291,33 @@ def _edges_both_ways(sel_ids: np.ndarray, sel_d: np.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# shared phases: levels, upper hierarchy, candidate helpers, repair
+# shared phases: levels, entry candidates, candidate helpers, repair
 # ---------------------------------------------------------------------------
+
+def _knn_device(table: jax.Array, q_ids: np.ndarray, x_ids: np.ndarray,
+                k: int, mode: str) -> Tuple[np.ndarray, np.ndarray]:
+    """Exact kNN of rows ``q_ids`` among rows ``x_ids`` of the gather table,
+    on the device: ``knn_ids_dists`` semantics (ids index ``x_ids``, raw
+    scores ascending, k <= len(x_ids)).
+
+    Shapes are fixed per call — queries in KNN_BLOCK-row blocks, the member
+    set padded to a KNN_BUCKET multiple and masked — so a build compiles a
+    handful of scans, not one per cluster.
+    """
+    nx = len(x_ids)
+    nxp = -(-nx // KNN_BUCKET) * KNN_BUCKET
+    xs = table[jnp.asarray(np.pad(x_ids, (0, nxp - nx))), 0]
+    live = jnp.asarray(np.arange(nxp) < nx)
+    out_i = np.empty((len(q_ids), k), np.int32)
+    out_d = np.empty((len(q_ids), k), np.float32)
+    for lo in range(0, len(q_ids), KNN_BLOCK):
+        blk = q_ids[lo:lo + KNN_BLOCK]
+        qs = table[jnp.asarray(np.pad(blk, (0, KNN_BLOCK - len(blk)))), 0]
+        d, i = flat_search(qs, xs, k, metric=mode, mask=live)
+        out_d[lo:lo + len(blk)] = np.asarray(d)[:len(blk)]
+        out_i[lo:lo + len(blk)] = np.asarray(i)[:len(blk)]
+    return out_i, out_d
+
 
 def _sample_levels(n: int, cfg: HNSWConfig,
                    rng: np.random.RandomState) -> np.ndarray:
@@ -313,41 +346,23 @@ def _rowwise_dists(vecs: np.ndarray, row_ids: np.ndarray,
     return out
 
 
-def _build_upper(vecs: np.ndarray, levels: np.ndarray, cfg: HNSWConfig,
-                 rng: np.random.RandomState, mode: str
-                 ) -> Tuple[np.ndarray, np.ndarray, int, int, int]:
-    """Per-layer kNN hierarchy among layer members (seed-builder semantics:
-    symmetrized member kNN + a couple of random member links per node)."""
+def _upper_members(levels: np.ndarray) -> Tuple[np.ndarray, int, int]:
+    """(upper_ids, max_level, entry_global): the layer>=1 members, which
+    search scans for its layer-0 entry, and the top layer's first node."""
     max_level = int(levels.max())
     upper_ids = np.where(levels >= 1)[0].astype(np.int32)
-    slot_of = {int(g): s for s, g in enumerate(upper_ids)}
-    l_top = max(max_level, 1)
-    upper_adj = np.full((len(upper_ids), l_top, cfg.M), PAD, dtype=np.int32)
-    for layer in range(1, max_level + 1):
-        members = upper_ids[levels[upper_ids] >= layer]
-        if len(members) <= 1:
-            continue
-        kk = min(max(cfg.M - 2, 1), len(members) - 1)
-        nn = knn_ids_dists(vecs[members], vecs[members], kk + 1,
-                           metric=mode)[0][:, 1:]
-        links = {int(g): set(int(members[j]) for j in nn[row_i])
-                 for row_i, g in enumerate(members)}
-        for row_i, g in enumerate(members):
-            for j in rng.randint(0, len(members), size=2):
-                if int(members[j]) != int(g):
-                    links[int(g)].add(int(members[j]))
-            links[int(g)].discard(int(g))
-            for nb in list(links[int(g)]):
-                links[nb].add(int(g))
-        for g, nbrs in links.items():
-            s = slot_of[g]
-            row = [slot_of[nb] for nb in sorted(nbrs)[: cfg.M]]
-            upper_adj[s, layer - 1, : len(row)] = row
-    top_members = upper_ids[levels[upper_ids] >= max_level]
-    entry_global = (int(top_members[0]) if len(top_members)
-                    else int(upper_ids[0]))
-    return (upper_ids, upper_adj, max_level, entry_global,
-            slot_of.get(entry_global, 0))
+    entry_global = int(upper_ids[levels[upper_ids] >= max_level][0])
+    return upper_ids, max_level, entry_global
+
+
+def _prefix_graph(table: jax.Array, adj0: jax.Array,
+                  graph_meta) -> HNSWGraph:
+    """The device graph the build's own beam searches traverse."""
+    upper_ids, _, entry_global = graph_meta
+    ids = jnp.asarray(upper_ids)
+    return HNSWGraph(vectors=table, adj0=adj0, upper_ids=ids,
+                     upper_vecs=table[ids, 0],
+                     entry_global=jnp.asarray(entry_global, jnp.int32))
 
 
 def _bfs_reachable(adj0: np.ndarray, entry: int) -> np.ndarray:
@@ -395,18 +410,17 @@ def _repair_connectivity(vecs: np.ndarray, adj0: np.ndarray,
 # mode 1: level-wise batched inserts over the frozen prefix
 # ---------------------------------------------------------------------------
 
-def _bulk_level(vecs: np.ndarray, cfg: HNSWConfig, rng: np.random.RandomState,
-                levels: np.ndarray, graph_meta, mode: str,
-                progress: Optional[ProgressFn]) -> Tuple[
+def _bulk_level(vecs: np.ndarray, corpus_dev: jax.Array, cfg: HNSWConfig,
+                rng: np.random.RandomState, levels: np.ndarray, graph_meta,
+                mode: str, progress: Optional[ProgressFn]) -> Tuple[
                     np.ndarray, np.ndarray, Dict]:
-    upper_ids, upper_adj, max_level, entry_global, entry_upper = graph_meta
+    upper_ids, max_level, _ = graph_meta
     n, _ = vecs.shape
     m0 = cfg.m0
     ef_build = cfg.ef_build or cfg.ef_construction
     k_base = min(m0 + cfg.M, n - 1)
     r = min(cfg.M, 8, n - 1)
 
-    corpus_dev = jnp.asarray(vecs)
     adj = jnp.full((n + 1, m0), PAD, jnp.int32)
     adj_d = jnp.full((n + 1, m0), jnp.inf, jnp.float32)
     adj_p = jnp.ones((n + 1, m0), jnp.int32)
@@ -445,7 +459,7 @@ def _bulk_level(vecs: np.ndarray, cfg: HNSWConfig, rng: np.random.RandomState,
     logger.debug("bulk level: bootstrap %d/%d", b0, n)
 
     # ---- batched level-wise growth over the frozen prefix
-    g_upper = (jnp.asarray(upper_ids), jnp.asarray(upper_adj))
+    g = _prefix_graph(corpus_dev, adj[:n], graph_meta)
     k_beam = min(k_base, ef_build)
     k_intra = min(8, batch - 1) if batch > 1 else 0
     width = max(cfg.expansion_width, 8)
@@ -457,10 +471,7 @@ def _bulk_level(vecs: np.ndarray, cfg: HNSWConfig, rng: np.random.RandomState,
             bids = np.concatenate(
                 [bids, np.full(batch - len(bids), n, np.int64)])
         q = vecs[np.minimum(bids, n - 1)]
-        g = HNSWGraph(vectors=corpus_dev, adj0=adj[:n],
-                      upper_ids=g_upper[0], upper_adj=g_upper[1],
-                      entry_global=jnp.asarray(entry_global, jnp.int32),
-                      entry_upper=jnp.asarray(entry_upper, jnp.int32))
+        g = g._replace(adj0=adj[:n])
         bd, bi = beam_search(g, jnp.asarray(q), k=k_beam, ef=ef_build,
                              max_level=max_level, metric=mode,
                              expansion_width=width)
@@ -500,9 +511,9 @@ def _bulk_level(vecs: np.ndarray, cfg: HNSWConfig, rng: np.random.RandomState,
 # mode 2: two-phase coarse build (cluster -> link -> stitch)
 # ---------------------------------------------------------------------------
 
-def _coarse_candidates(vecs: np.ndarray, cfg: HNSWConfig,
-                       rng: np.random.RandomState, mode: str,
-                       progress: Optional[ProgressFn]
+def _coarse_candidates(vecs: np.ndarray, table: jax.Array,
+                       cfg: HNSWConfig, rng: np.random.RandomState,
+                       mode: str, progress: Optional[ProgressFn]
                        ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, int]:
     """k-means cluster the corpus, then exact-kNN each node against the
     union of its two nearest clusters.  Returns (cand_ids, cand_d,
@@ -518,7 +529,8 @@ def _coarse_candidates(vecs: np.ndarray, cfg: HNSWConfig,
     kc = min(max(cfg.m0 + cfg.M, ef_b // 2) + 2, n)
 
     if nlist <= 1:
-        ids, dd = knn_ids_dists(vecs, vecs, kc, metric=mode)
+        all_ids = np.arange(n)
+        ids, dd = _knn_device(table, all_ids, all_ids, kc, mode)
         return ids, dd, np.zeros(n, np.float32), 1
 
     samp = rng.choice(n, size=min(n, max(nlist * 64, 4096)), replace=False)
@@ -557,7 +569,7 @@ def _coarse_candidates(vecs: np.ndarray, cfg: HNSWConfig,
             continue
         mem = np.where((a1 == c) | (a2 == c))[0]
         kk = min(kc, len(mem))
-        loc, dd = knn_ids_dists(vecs[prim], vecs[mem], kk, metric=mode)
+        loc, dd = _knn_device(table, prim, mem, kk, mode)
         cand_i[prim, :kk] = mem[loc].astype(np.int32)
         cand_d[prim, :kk] = dd
         if progress is not None:
@@ -567,28 +579,30 @@ def _coarse_candidates(vecs: np.ndarray, cfg: HNSWConfig,
     return cand_i, cand_d, margin, nlist
 
 
-def _bulk_coarse(vecs: np.ndarray, cfg: HNSWConfig,
+def _bulk_coarse(vecs: np.ndarray, corpus_dev: jax.Array, cfg: HNSWConfig,
                  rng: np.random.RandomState, levels: np.ndarray, graph_meta,
                  mode: str, progress: Optional[ProgressFn]
                  ) -> Tuple[np.ndarray, np.ndarray, Dict]:
-    upper_ids, upper_adj, max_level, entry_global, entry_upper = graph_meta
+    _, max_level, _ = graph_meta
     n, _ = vecs.shape
     m0 = cfg.m0
     r = min(cfg.M, 8, n - 1)
 
+    t0 = time.perf_counter()
     cand_i, cand_d, margin, nlist = _coarse_candidates(
-        vecs, cfg, rng, mode, progress)
+        vecs, corpus_dev, cfg, rng, mode, progress)
+    t_cand = time.perf_counter()
     if r > 0:
         rnd = rng.randint(0, n, size=(n, r)).astype(np.int32)
         cand_i = np.concatenate([cand_i, rnd], axis=1)
         cand_d = np.concatenate(
             [cand_d, _rowwise_dists(vecs, np.arange(n), rnd, mode)], axis=1)
 
-    corpus_dev = jnp.asarray(vecs)
     all_ids = np.arange(n, dtype=np.int32)
     sel_i, sel_d, sel_p = _prune_chunks(corpus_dev, all_ids, cand_i, cand_d,
                                         m=m0, mode=mode,
                                         keep_pruned=cfg.keep_pruned)
+    t_prune = time.perf_counter()
     if progress is not None:
         progress("prune", n, n)
 
@@ -599,6 +613,8 @@ def _bulk_coarse(vecs: np.ndarray, cfg: HNSWConfig,
     adj, adj_d, adj_p = _merge_cap(
         adj, adj_d, adj_p, jnp.asarray(tgt), jnp.asarray(src),
         jnp.asarray(dd), jnp.asarray(pp), m=m0)
+    adj.block_until_ready()
+    t_merge = time.perf_counter()
 
     # ---- cross-cluster stitching: boundary nodes re-search the built graph
     n_stitch = int(round(cfg.stitch_frac * n)) if nlist > 1 else 0
@@ -608,6 +624,7 @@ def _bulk_coarse(vecs: np.ndarray, cfg: HNSWConfig,
         width = max(cfg.expansion_width, 8)
         boundary = np.argsort(margin, kind="stable")[:n_stitch]
         batch = min(cfg.build_batch, n_stitch)
+        g = _prefix_graph(corpus_dev, adj[:n], graph_meta)
         for lo in range(0, n_stitch, batch):
             hi = min(lo + batch, n_stitch)
             bids = boundary[lo:hi]
@@ -615,11 +632,7 @@ def _bulk_coarse(vecs: np.ndarray, cfg: HNSWConfig,
                 bids = np.concatenate(
                     [bids, np.full(batch - len(bids), n, np.int64)])
             q = vecs[np.minimum(bids, n - 1)]
-            g = HNSWGraph(vectors=corpus_dev, adj0=adj[:n],
-                          upper_ids=jnp.asarray(upper_ids),
-                          upper_adj=jnp.asarray(upper_adj),
-                          entry_global=jnp.asarray(entry_global, jnp.int32),
-                          entry_upper=jnp.asarray(entry_upper, jnp.int32))
+            g = g._replace(adj0=adj[:n])
             bd, bi = beam_search(g, jnp.asarray(q), k=k_st, ef=ef_st,
                                  max_level=max_level, metric=mode,
                                  expansion_width=width)
@@ -648,8 +661,14 @@ def _bulk_coarse(vecs: np.ndarray, cfg: HNSWConfig,
 
     adj0 = np.array(adj[:n])
     adj0_d = np.array(adj_d[:n])
+    t_stitch = time.perf_counter()
     return adj0, adj0_d, {"build_clusters": nlist,
-                          "build_stitched": n_stitch}
+                          "build_stitched": n_stitch,
+                          # host-clock seconds per phase (device work synced)
+                          "build_s_candidates": t_cand - t0,
+                          "build_s_prune": t_prune - t_cand,
+                          "build_s_merge": t_merge - t_prune,
+                          "build_s_stitch": t_stitch - t_merge}
 
 
 # ---------------------------------------------------------------------------
@@ -683,21 +702,23 @@ def bulk_build_device(vectors: np.ndarray,
 
     rng = np.random.RandomState(cfg.seed)
     levels = _sample_levels(n, cfg, rng)
-    graph_meta = _build_upper(vecs, levels, cfg, rng, dev_metric)
-    upper_ids, upper_adj, max_level, entry_global, entry_upper = graph_meta
+    table = gather_table(vecs)
+    graph_meta = _upper_members(levels)
+    upper_ids, max_level, entry_global = graph_meta
 
     build_fn = _bulk_coarse if mode == "coarse" else _bulk_level
-    adj0, adj0_d, info = build_fn(vecs, cfg, rng, levels, graph_meta,
+    adj0, adj0_d, info = build_fn(vecs, table, cfg, rng, levels, graph_meta,
                                   dev_metric, progress)
 
+    t_layer0 = time.perf_counter()
     repaired = _repair_connectivity(vecs, adj0, adj0_d, entry_global,
                                     dev_metric)
     if repaired:
         logger.info("bulk build: reattached %d stranded nodes", repaired)
-    info.update({"builder_mode": mode, "build_repaired": repaired})
+    info.update({"builder_mode": mode, "build_repaired": repaired,
+                 "build_s_repair": time.perf_counter() - t_layer0})
 
     return PackedHNSW(config=cfg, vectors=vecs, adj0=adj0,
-                      upper_ids=upper_ids, upper_adj=upper_adj,
-                      levels=levels, entry_global=entry_global,
-                      entry_upper=entry_upper, max_level=max_level,
+                      upper_ids=upper_ids, levels=levels,
+                      entry_global=entry_global, max_level=max_level,
                       build_info=info)

@@ -3,8 +3,9 @@
 The CPU algorithm's dynamic structures are re-expressed as fixed-shape tensor
 ops so the whole search jits, vmaps over query batches, and shards:
 
-  greedy upper-layer descent   -> ``lax.while_loop`` over a gathered (M,)
-                                  neighbour row + masked argmin
+  upper-layer descent          -> one exact scan of the layer-1 members
+                                  (a batched matmul); the nearest is the
+                                  layer-0 entry point
   candidate min-heap / results -> one fused (ef,) candidate buffer maintained
                                   by ``lax.top_k`` over (ef + B·M0) merged rows
   visited hash-set             -> packed bitmask, ``ceil(N/32)`` uint32 words,
@@ -34,10 +35,14 @@ Distance evaluation is pluggable per graph payload (``metric``):
                 words (beam_gather_hamming) — monotone affine in -dot of
                 the ±1 sign vectors
 
-so quantized engines traverse in code domain; upper-layer descent (a handful
-of scalar steps) keeps using the float proxy vectors.  The loop trip count is
-bounded (``max_iters``), giving XLA a fully static program; ``with_iters``
-returns the per-query trip counter for benchmarks/observability.
+so quantized engines traverse in code domain; the entry scan over the
+upper-layer members keeps using the float proxy vectors.  Every corpus-sized
+array ships as a gather table (kernels/beam_gather.gather_table: rows
+(N, 1, Wp), lanes zero-padded to 128) so the kernels can DMA single rows;
+queries are zero-padded to the same width once, on entry.  The loop trip
+count is bounded (``max_iters``), giving XLA a fully static program;
+``with_iters`` returns the per-query trip counter for
+benchmarks/observability.
 """
 
 from __future__ import annotations
@@ -50,25 +55,28 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import ops
+from ..kernels.beam_gather import gather_table, pad_lanes
+from .flat import flat_search
 from .hnsw_build import PAD, PackedHNSW, make_dist_fn, preprocess_vectors
 
 Array = jax.Array
 INF = jnp.inf
 
 DEFAULT_EXPANSION_WIDTH = 4
+ENTRY_CHUNK = 8192   # upper members scored per step of the entry scan
 
 
 class HNSWGraph(NamedTuple):
     """Device-resident packed graph (all jnp arrays; static meta travels
     separately as jit-static args)."""
 
-    vectors: Array      # (N, D) float32, metric-preprocessed
+    vectors: Array      # (N, 1, Dp) float32 gather table, metric-preprocessed
     adj0: Array         # (N, M0) int32, PAD = -1
-    upper_ids: Array    # (U,) int32 upper-slot -> global id
-    upper_adj: Array    # (U, L_top, M) int32 upper-slot ids, PAD = -1
-    entry_global: Array  # () int32
-    entry_upper: Array   # () int32
-    codes: Optional[Array] = None  # (N, m) PQ codes / (N, W) packed BQ words
+    upper_ids: Array    # (U,) int32 upper-layer members (entry candidates)
+    upper_vecs: Array   # (U, Dp) float32 their proxy rows (the entry scan)
+    entry_global: Array  # () int32, the entry of a graph without layers
+    # (N, 1, mp) int32 PQ codes / (N, 1, Wp) uint32 packed BQ words (tables)
+    codes: Optional[Array] = None
 
 
 def to_device(packed: PackedHNSW,
@@ -77,55 +85,42 @@ def to_device(packed: PackedHNSW,
 
     ``codes`` optionally ships the quantized corpus (PQ uint codes or packed
     BQ uint32 words) alongside the float proxy vectors, enabling the
-    code-domain traversal modes ("adc"/"hamming") of :func:`search`.
+    code-domain traversal modes ("adc"/"hamming") of :func:`search`.  Both
+    ship as gather tables.
     """
+    table = gather_table(np.asarray(packed.vectors, dtype=np.float32))
+    upper_ids = jnp.asarray(packed.upper_ids)
     g = HNSWGraph(
-        vectors=jnp.asarray(packed.vectors, dtype=jnp.float32),
+        vectors=table,
         adj0=jnp.asarray(packed.adj0),
-        upper_ids=jnp.asarray(packed.upper_ids),
-        upper_adj=jnp.asarray(packed.upper_adj),
+        upper_ids=upper_ids,
+        upper_vecs=table[upper_ids, 0],
         entry_global=jnp.asarray(packed.entry_global, dtype=jnp.int32),
-        entry_upper=jnp.asarray(packed.entry_upper, dtype=jnp.int32),
-        codes=None if codes is None else jnp.asarray(codes),
+        codes=None if codes is None else gather_table(np.asarray(codes)),
     )
     metric = "l2" if packed.config.metric == "l2" else "dot"
     return g, int(packed.max_level), metric
 
 
-def _dist_rows(q: Array, rows: Array, metric: str) -> Array:
-    """q (D,) vs rows (M, D) -> (M,) raw scores (smaller == closer)."""
-    if metric == "l2":
-        d = rows - q[None, :]
-        return jnp.sum(d * d, axis=-1)
-    return -(rows @ q)  # dot / pre-normalized cosine
+def _entry_points(g: HNSWGraph, queries: Array, max_level: int,
+                  metric: str) -> Array:
+    """(Q,) layer-0 entry points: for each query, the upper-layer member
+    nearest to it, found by one exact scan of the layer-1 members.
 
-
-def _descend(q: Array, g: HNSWGraph, layer: int, cur: Array,
-             metric: str) -> Array:
-    """Greedy move-to-nearest at one upper layer; cur is an upper-slot id."""
-
-    def cur_dist(slot):
-        return _dist_rows(q, g.vectors[g.upper_ids[slot]][None, :], metric)[0]
-
-    def cond(state):
-        _, _, moved = state
-        return moved
-
-    def body(state):
-        slot, d_cur, _ = state
-        nbrs = g.upper_adj[slot, layer]              # (M,) upper-slot ids
-        valid = nbrs != PAD
-        safe = jnp.maximum(nbrs, 0)
-        rows = g.vectors[g.upper_ids[safe]]          # (M, D) gather
-        d = jnp.where(valid, _dist_rows(q, rows, metric), INF)
-        j = jnp.argmin(d)
-        better = d[j] < d_cur
-        return (jnp.where(better, nbrs[j], slot),
-                jnp.where(better, d[j], d_cur), better)
-
-    slot, _, _ = jax.lax.while_loop(
-        cond, body, (cur, cur_dist(cur), jnp.array(True)))
-    return slot
+    It stands in for the greedy per-layer descent of the paper, so no
+    upper-layer links are stored.  A descent over the bulk builder's
+    member-kNN upper layers stalled far from the query (no nearer than a
+    random entry), and the ef-bounded layer-0 beam then missed neighbours it
+    finds from a near entry.  The scan is one (Q, D) x (D, U) matmul over
+    ``g.upper_vecs``, streamed in U-chunks; its answer is at least as near as
+    any descent's, which also ends on a layer-1 member.
+    """
+    u = g.upper_ids.shape[0]
+    if max_level == 0 or u == 0:
+        return jnp.broadcast_to(g.entry_global, (queries.shape[0],))
+    _, best = flat_search(queries, g.upper_vecs, 1, metric=metric,
+                          chunk=ENTRY_CHUNK if u > ENTRY_CHUNK else None)
+    return g.upper_ids[best[:, 0]]
 
 
 def _make_block_dist(g: HNSWGraph, q: Array, q_code: Optional[Array],
@@ -228,7 +223,7 @@ def search(g: HNSWGraph, queries: Array, *, k: int, ef: int,
       g: device graph from :func:`to_device`.
       queries: (Q, D) — pre-normalize for cosine (to_device stores the corpus
         normalized; use metric="dot").  For code-domain metrics this is the
-        float proxy used by the upper-layer descent (PQ: the normalized
+        float proxy used by the entry scan (PQ: the normalized
         query; BQ: the ±1 sign vector).
       k: neighbours to return (k <= ef).
       ef: beam width (result-buffer size).
@@ -256,26 +251,23 @@ def search(g: HNSWGraph, queries: Array, *, k: int, ef: int,
         raise ValueError(f"metric {metric!r} needs g.codes and q_codes")
     # a beam can't pop more candidates than the buffer holds (tiny corpora)
     width = max(1, min(int(expansion_width), ef))
-    descent_metric = {"adc": "l2", "hamming": "dot"}.get(metric, metric)
+    entry_metric = {"adc": "l2", "hamming": "dot"}.get(metric, metric)
     n = g.vectors.shape[0]
     n_words = (n + 31) // 32
-    queries = queries.astype(jnp.float32)
+    queries = pad_lanes(queries.astype(jnp.float32), g.vectors.shape[-1])
 
-    def one(q, qc):
-        slot = g.entry_upper
-        for layer in range(max_level, 0, -1):       # static unroll, tiny
-            slot = _descend(q, g, layer - 1, slot, descent_metric)
-        ep = jnp.where(jnp.asarray(max_level > 0),
-                       g.upper_ids[slot], g.entry_global)
+    eps = _entry_points(g, queries, max_level, entry_metric)
+
+    def one(q, qc, ep):
         block_dist = _make_block_dist(g, q, qc, metric)
         d, ids, iters = _beam_search_base(g, ep, ef, width, max_iters,
                                           n_words, block_dist)
         return d[:k], ids[:k], iters
 
     if q_codes is None:
-        d, ids, iters = jax.vmap(lambda q: one(q, None))(queries)
+        d, ids, iters = jax.vmap(lambda q, ep: one(q, None, ep))(queries, eps)
     else:
-        d, ids, iters = jax.vmap(one)(queries, q_codes)
+        d, ids, iters = jax.vmap(one)(queries, q_codes, eps)
     return (d, ids, iters) if with_iters else (d, ids)
 
 
@@ -297,24 +289,11 @@ def search_numpy_reference(packed: PackedHNSW, queries: np.ndarray, k: int,
     out_i = np.full((len(q_all), k), -1, dtype=np.int32)
 
     width = min(width, ef)                     # mirror the device clamp
+    upper = packed.upper_ids.astype(np.int64)
     for qi, q in enumerate(q_all):
-        # descent
-        slot = packed.entry_upper
-        for layer in range(packed.max_level, 0, -1):
-            while True:
-                nbrs = packed.upper_adj[slot, layer - 1]
-                nbrs = nbrs[nbrs != PAD]
-                if len(nbrs) == 0:
-                    break
-                d_cur = dist(q, np.array([packed.upper_ids[slot]], np.int64))[0]
-                ds = dist(q, packed.upper_ids[nbrs].astype(np.int64))
-                j = int(np.argmin(ds))
-                if ds[j] < d_cur:
-                    slot = int(nbrs[j])
-                else:
-                    break
-        ep = int(packed.upper_ids[slot]) if packed.max_level > 0 \
-            else packed.entry_global
+        # entry: the nearest upper-layer member (first among ties)
+        ep = int(upper[np.argmin(dist(q, upper))]) \
+            if packed.max_level > 0 and len(upper) else packed.entry_global
         # wide beam
         cand_d = np.full((ef,), np.inf, np.float32)
         cand_i = np.full((ef,), -1, np.int64)

@@ -11,6 +11,8 @@ algorithms under test*:
     heavy-tailed (exponential magnitudes), block-sparse, L2-comparable —
     the harder, flatter distance distribution of SIFT.
   * gaussian_mixture — generic clustered corpus for quantizer tests.
+  * embedding_like — text-embedding-shaped corpus (topic / subtopic /
+    noise clusters over a power-law spectrum) for deployment-scale runs.
   * token streams — Zipf-distributed LM batches for the architecture cells.
 
 All generators are deterministic in (seed, shape).
@@ -47,6 +49,46 @@ def gaussian_mixture(n: int, dim: int, n_clusters: int = 32,
     if return_labels:
         return x.astype(np.float32), labels
     return x.astype(np.float32)
+
+
+# embedding_like: clusters at three scales over one power-law spectrum
+EMB_TOPICS, EMB_SUBTOPICS = 1024, 32
+EMB_DECAY = 1.0                  # coordinate variance falls off as i**-1
+EMB_SUB_SCALE, EMB_NOISE = 0.5, 0.3
+EMB_CHUNK = 1 << 16              # rows drawn per block
+
+
+def embedding_like(n: int, dim: int, seed: int = 0) -> np.ndarray:
+    """Text-embedding-shaped corpus for deployment-scale serving runs.
+
+    Rows are topic centre + subtopic offset + noise: clusters at three
+    scales, as semantic embeddings have (subject, sub-subject, near
+    paraphrase).  Every level draws from the same power-law spectrum, the
+    anisotropic shape of sentence embeddings; the finer levels are scaled
+    by EMB_SUB_SCALE and EMB_NOISE against the topic centres.  Cosine is
+    the intended metric.
+
+    Rows come in EMB_CHUNK-row blocks, so memory stays at the output plus
+    one block.  Hold-out queries are the tail rows of one
+    ``n + n_queries`` draw: same clusters, disjoint rows.
+    """
+    rng = np.random.default_rng(seed)
+    std = np.arange(1, dim + 1, dtype=np.float32) ** np.float32(-EMB_DECAY / 2)
+    topics = rng.standard_normal((EMB_TOPICS, dim), dtype=np.float32) * std
+    subs = rng.standard_normal((EMB_TOPICS, EMB_SUBTOPICS, dim),
+                               dtype=np.float32) * (std * EMB_SUB_SCALE)
+    noise = std * np.float32(EMB_NOISE)
+    out = np.empty((n, dim), dtype=np.float32)
+    for lo in range(0, n, EMB_CHUNK):
+        hi = min(lo + EMB_CHUNK, n)
+        t = rng.integers(0, EMB_TOPICS, size=hi - lo)
+        u = rng.integers(0, EMB_SUBTOPICS, size=hi - lo)
+        block = rng.standard_normal((hi - lo, dim), dtype=np.float32)
+        block *= noise
+        block += topics[t]
+        block += subs[t, u]
+        out[lo:hi] = block
+    return out
 
 
 def fashion_mnist_like(n: int, seed: int = 0) -> np.ndarray:

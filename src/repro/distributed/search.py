@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import axis_size as _axis_size
-from ..compat import shard_map_compat as _shard_map
 from ..launch.mesh import batch_axes, mesh_axis_sizes
 
 Array = jax.Array
@@ -50,7 +48,7 @@ def _merge_shard_topk(d: Array, k: int, rows) -> Tuple[Array, Array]:
     neg, idx = jax.lax.top_k(-d, kk)
     shard = jax.lax.axis_index(rows[0])
     for ax in rows[1:]:
-        shard = shard * _axis_size(ax) + jax.lax.axis_index(ax)
+        shard = shard * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     gids = (idx + shard * n_local).astype(jnp.int32)
     cand_d = jax.lax.all_gather(-neg, rows, axis=1, tiled=True)
     cand_i = jax.lax.all_gather(gids, rows, axis=1, tiled=True)
@@ -69,9 +67,10 @@ def _build(mesh: Mesh, local_distances: Callable, k: int,
     # replication checking off: after the cross-shard all_gather + top_k the
     # outputs are value-identical on every shard (exactness property-tested),
     # but the static varying-axes checker cannot infer that through gather.
-    fn = _shard_map(local, mesh,
-                    (corpus_spec, query_spec),
-                    (P(None, None), P(None, None)))
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(corpus_spec, query_spec),
+                       out_specs=(P(None, None), P(None, None)),
+                       check_vma=False)
     return jax.jit(fn,
                    in_shardings=(NamedSharding(mesh, corpus_spec),
                                  NamedSharding(mesh, query_spec)),

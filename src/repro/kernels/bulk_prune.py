@@ -8,8 +8,8 @@ row of the pair matrix.
 
 The memory pattern is the same data-dependent row gather as wide-beam
 traversal (beam_gather.py) — candidate ids ride as a scalar-prefetch
-argument, the corpus stays in HBM (``memory_space=ANY``), and the C rows
-are DMA'd into a VMEM scratch tile — but the fused math is a *self*
+argument, the corpus gather table stays in HBM (``memory_space=ANY``), and
+the C rows are DMA'd into a VMEM scratch tile — but the fused math is a *self*
 contraction: one (C, D) × (D, C) MXU matmul producing the full pair
 matrix, instead of C separate query-row gathers.
 
@@ -27,14 +27,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .beam_gather import _gather_rows
+from .beam_gather import HIGHEST, _gather_rows, check_table
 
 
 def _pair_kernel(ids_ref, corpus_ref, o_ref, rows, sems, *, c: int,
                  mode: str):
-    _gather_rows(ids_ref, corpus_ref, rows, sems, c)
-    r = rows[...].astype(jnp.float32)             # (C, D)
-    g = jax.lax.dot_general(r, r, (((1,), (1,)), ((), ())),
+    r = _gather_rows(ids_ref, corpus_ref, rows, sems, c)   # (C, Dp)
+    g = jax.lax.dot_general(r, r, (((1,), (1,)), ((), ())), precision=HIGHEST,
                             preferred_element_type=jnp.float32)   # (C, C)
     if mode == "l2":
         nn = jnp.sum(r * r, axis=-1)              # (C,)
@@ -47,19 +46,21 @@ def _pair_kernel(ids_ref, corpus_ref, o_ref, rows, sems, *, c: int,
 def pair_gather_kernel(ids: jax.Array, corpus: jax.Array, *,
                        mode: str = "l2",
                        interpret: bool = False) -> jax.Array:
-    """ids (C,) × corpus (N, D) -> (C, C) float32 pairwise distances."""
+    """ids (C,) × corpus table (N, 1, Dp) -> (C, C) float32 pairwise
+    distances."""
     if mode not in ("l2", "dot"):
         raise ValueError(f"mode {mode!r}")
+    check_table(corpus, jnp.float32)
+    table = corpus
     c = ids.shape[0]
-    d = corpus.shape[1]
     cp = -(-c // 8) * 8                            # sublane-align the tile
     ids_p = jnp.pad(ids.astype(jnp.int32), (0, cp - c))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((cp, cp), lambda i, ids: (0, 0)),
-        scratch_shapes=[pltpu.VMEM((cp, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((cp, 1, table.shape[-1]), jnp.float32),
                         pltpu.SemaphoreType.DMA((cp,))],
     )
     out = pl.pallas_call(
@@ -67,5 +68,5 @@ def pair_gather_kernel(ids: jax.Array, corpus: jax.Array, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((cp, cp), jnp.float32),
         interpret=interpret,
-    )(ids_p, corpus.astype(jnp.float32))
+    )(ids_p, table)
     return out[:c, :c]

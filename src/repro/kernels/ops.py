@@ -1,23 +1,23 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Platform dispatch: on TPU the compiled kernels run natively; elsewhere (this
-CPU container) they execute in ``interpret=True`` mode — same kernel body,
-Python-evaluated — so correctness is validated everywhere while the BlockSpec
-tiling is real TPU structure.  ``force_ref=True`` (or env QUANTIXAR_REF=1)
-routes to the pure-jnp oracle instead, which is what the engine uses for
-speed on CPU.
+Platform dispatch: on TPU the compiled kernels always run natively — never
+in interpret mode and never through an oracle unless a caller passes
+``force_ref=True`` (tests).  On other backends the library defaults to the
+pure-jnp oracles in ref.py for speed; ``force_ref=False`` there runs the
+kernel body in ``interpret=True`` mode (Python-evaluated, same BlockSpec
+tiling), which is how tests validate the kernels off-chip.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 
 from . import ref
 from .beam_gather import (beam_gather_adc_kernel, beam_gather_hamming_kernel,
-                          beam_gather_kernel)
+                          beam_gather_kernel, check_table, pad_lanes)
 from .bulk_prune import pair_gather_kernel
 from .hamming import hamming_kernel
 from .l2 import l2_distance_kernel
@@ -32,13 +32,16 @@ def _interpret() -> bool:
 
 
 def _use_ref(force_ref: Optional[bool]) -> bool:
-    if force_ref is not None:
-        return force_ref
-    if os.environ.get("QUANTIXAR_REF", ""):
-        return True
-    # On non-TPU backends interpret-mode Pallas is correct but slow; default
-    # to the oracle for library use. Tests pass force_ref=False explicitly.
-    return _interpret()
+    # off-TPU, interpret-mode Pallas is correct but slow: default to the
+    # oracle there.  Tests pass force_ref explicitly.
+    return _interpret() if force_ref is None else force_ref
+
+
+def _table_rows(table: Array, dtype) -> Array:
+    """Gather table (N, 1, Wp) -> (N, Wp) for the oracles, which index rows
+    directly."""
+    check_table(table, dtype)
+    return table[:, 0, :]
 
 
 def l2_distances(queries: Array, corpus: Array, *,
@@ -74,16 +77,21 @@ def hamming_distances(q_codes: Array, x_codes: Array, *,
 # ------------------------------------------------- wide-beam gather-distance
 # Per-query fused (ids -> row gather -> distance) evaluators for the HNSW
 # wide-beam traversal (core/hnsw_search.py).  Called under vmap/while_loop;
-# same ref/kernel dispatch contract as the dense kernels above.
+# same ref/kernel dispatch contract as the dense kernels above.  The corpus
+# operand must be a gather table (beam_gather.gather_table, built once where
+# the corpus ships); queries are zero-padded to the table's lane width.
 
 def beam_gather_distances(q: Array, ids: Array, corpus: Array, *,
                           mode: str = "l2",
                           force_ref: Optional[bool] = None, **tiles) -> Array:
-    """q (D,) × ids (L,) × corpus (N, D) -> (L,) float32 (l2 | dot)."""
+    """q (D,) × ids (L,) × corpus table (N, 1, Dp) -> (L,) float32
+    (l2 | dot)."""
     if _use_ref(force_ref):
+        rows = _table_rows(corpus, jnp.float32)
+        q = pad_lanes(q, rows.shape[-1])
         if mode == "l2":
-            return ref.beam_gather_l2_ref(q, ids, corpus)
-        return ref.beam_gather_dot_ref(q, ids, corpus)
+            return ref.beam_gather_l2_ref(q, ids, rows)
+        return ref.beam_gather_dot_ref(q, ids, rows)
     return beam_gather_kernel(q, ids, corpus, mode=mode,
                               interpret=_interpret(), **tiles)
 
@@ -92,30 +100,37 @@ def pair_gather_distances(ids: Array, corpus: Array, *,
                           mode: str = "l2",
                           force_ref: Optional[bool] = None,
                           **tiles) -> Array:
-    """ids (C,) × corpus (N, D) -> (C, C) float32 pairwise distances
-    among the gathered rows (l2 | dot) — the bulk-prune pair matrix."""
+    """ids (C,) × corpus table (N, 1, Dp) -> (C, C) float32 pairwise
+    distances among the gathered rows (l2 | dot) — the bulk-prune pair
+    matrix."""
     if _use_ref(force_ref):
+        rows = _table_rows(corpus, jnp.float32)
         if mode == "l2":
-            return ref.pair_gather_l2_ref(ids, corpus)
-        return ref.pair_gather_dot_ref(ids, corpus)
+            return ref.pair_gather_l2_ref(ids, rows)
+        return ref.pair_gather_dot_ref(ids, rows)
     return pair_gather_kernel(ids, corpus, mode=mode,
                               interpret=_interpret(), **tiles)
 
 
 def beam_gather_adc(lut: Array, ids: Array, codes: Array, *,
                     force_ref: Optional[bool] = None, **tiles) -> Array:
-    """lut (m, k) × ids (L,) × codes (N, m) -> (L,) float32 ADC distances."""
+    """lut (m, k) × ids (L,) × code table (N, 1, mp) -> (L,) float32 ADC
+    distances."""
     if _use_ref(force_ref):
-        return ref.beam_gather_adc_ref(lut, ids, codes)
+        rows = _table_rows(codes, jnp.int32)[:, :lut.shape[0]]
+        return ref.beam_gather_adc_ref(lut, ids, rows)
     return beam_gather_adc_kernel(lut, ids, codes,
                                   interpret=_interpret(), **tiles)
 
 
 def beam_gather_hamming(q_code: Array, ids: Array, codes: Array, *,
                         force_ref: Optional[bool] = None, **tiles) -> Array:
-    """q_code (W,) × ids (L,) × codes (N, W) uint32 -> (L,) int32 Hamming."""
+    """q_code (W,) × ids (L,) × word table (N, 1, Wp) uint32 -> (L,) int32
+    Hamming."""
     if _use_ref(force_ref):
-        return ref.beam_gather_hamming_ref(q_code, ids, codes)
+        rows = _table_rows(codes, jnp.uint32)
+        return ref.beam_gather_hamming_ref(
+            pad_lanes(q_code, rows.shape[-1]), ids, rows)
     return beam_gather_hamming_kernel(q_code, ids, codes,
                                       interpret=_interpret(), **tiles)
 

@@ -2,6 +2,8 @@
 
 Each function is the mathematical definition with no tiling/padding tricks;
 kernel tests sweep shapes & dtypes and assert_allclose against these.
+Matmuls run at HIGHEST precision, so on a TPU the oracles stay f32-exact
+instead of rounding operands to bf16.
 """
 
 from __future__ import annotations
@@ -12,19 +14,23 @@ import jax.numpy as jnp
 Array = jax.Array
 
 
+def _mm(a: Array, b: Array) -> Array:
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def l2_distance_ref(queries: Array, corpus: Array) -> Array:
     """(Q, D) × (N, D) -> (Q, N) squared L2, float32 accumulation."""
     q = queries.astype(jnp.float32)
     x = corpus.astype(jnp.float32)
     qq = jnp.sum(q * q, axis=1)
     xx = jnp.sum(x * x, axis=1)
-    d = qq[:, None] + xx[None, :] - 2.0 * (q @ x.T)
+    d = qq[:, None] + xx[None, :] - 2.0 * _mm(q, x.T)
     return jnp.maximum(d, 0.0)
 
 
 def dot_distance_ref(queries: Array, corpus: Array) -> Array:
     """(Q, D) × (N, D) -> (Q, N) negative inner product, float32 accum."""
-    return -(queries.astype(jnp.float32) @ corpus.astype(jnp.float32).T)
+    return -_mm(queries.astype(jnp.float32), corpus.astype(jnp.float32).T)
 
 
 def pq_adc_ref(lut: Array, codes: Array) -> Array:
@@ -61,7 +67,7 @@ def beam_gather_l2_ref(q: Array, ids: Array, corpus: Array) -> Array:
 
 def beam_gather_dot_ref(q: Array, ids: Array, corpus: Array) -> Array:
     """Fused gather-distance, negative inner product variant -> (L,)."""
-    return -(corpus[ids] @ q)
+    return -_mm(corpus[ids], q)
 
 
 def beam_gather_adc_ref(lut: Array, ids: Array, codes: Array) -> Array:
@@ -91,7 +97,7 @@ def pair_gather_l2_ref(ids: Array, corpus: Array) -> Array:
     Norm-expansion form (never materializes a (C, C, D) diff tensor — this
     oracle runs under vmap over prune batches on the CPU fallback path)."""
     rows = corpus[ids]                     # (C, D)
-    g = rows @ rows.T
+    g = _mm(rows, rows.T)
     nn = jnp.sum(rows * rows, axis=-1)
     return jnp.maximum(nn[:, None] + nn[None, :] - 2.0 * g, 0.0)
 
@@ -99,7 +105,7 @@ def pair_gather_l2_ref(ids: Array, corpus: Array) -> Array:
 def pair_gather_dot_ref(ids: Array, corpus: Array) -> Array:
     """Fused gather + pairwise negated inner product -> (C, C)."""
     rows = corpus[ids]
-    return -(rows @ rows.T)
+    return -_mm(rows, rows.T)
 
 
 def slstm_sequence_ref(gates_x: Array, r: Array, b: Array,

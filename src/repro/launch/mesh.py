@@ -22,10 +22,15 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
-    """Small mesh over whatever devices exist (tests / CPU examples)."""
+    """(data, model) mesh over the local devices (tests / examples).
+
+    Raises when fewer devices exist than the mesh asks for: a mesh that
+    silently shrank would run, and report, a different program.
+    """
     n = len(jax.devices())
     if data * model > n:
-        data, model = n, 1
+        raise ValueError(f"a {data}x{model} mesh needs {data * model} "
+                         f"devices; {n} found")
     return jax.make_mesh((data, model), ("data", "model"))
 
 

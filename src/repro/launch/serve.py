@@ -22,30 +22,41 @@ import argparse
 import sys
 import threading
 import time
+from typing import Any, Callable, List, Optional
+
+import numpy as np
 
 from ..api import (BatcherConfig, Database, KeywordField, QuantixarClient,
                    VectorField)
 from ..core.hnsw_build import HNSWConfig, exact_knn
 from ..data.synthetic import gaussian_mixture
+from .compile_cache import enable_compile_cache
 
 
 def build_database(n: int, dim: int, index: str, quant: str,
                    seed: int = 0, max_batch: int = 32,
                    max_wait_ms: float = 2.0, expansion_width: int = 4,
-                   shards: int = 1):
+                   shards: int = 1, corpus: Optional[np.ndarray] = None,
+                   **vector_opts):
     """Returns (db, corpus) so callers score recall against exactly the
     vectors that were indexed.  `shards > 1` builds a `ShardedCollection`
-    (hash-partitioned scatter-gather) instead of a single engine."""
+    (hash-partitioned scatter-gather) instead of a single engine.
+
+    `corpus` indexes the given (n, dim) rows instead of a seeded gaussian
+    mixture; `vector_opts` go to `VectorField` (e.g. `pq=`, `bq=`,
+    `hnsw=`, `ef_search=`).  Row i is id `vec-{i}` with payload
+    `{"shard": f"s{i % 8}"}`."""
+    vector_opts.setdefault("hnsw", HNSWConfig(expansion_width=expansion_width))
     db = Database()
     col = db.create_collection(
         name="corpus",
         vector=VectorField(dim=dim, index=index, quantization=quant,
-                           builder="bulk",
-                           hnsw=HNSWConfig(expansion_width=expansion_width)),
+                           builder="bulk", **vector_opts),
         fields=(KeywordField("shard"),),
         batcher=BatcherConfig(max_batch=max_batch, max_wait_ms=max_wait_ms),
         shards=shards)
-    corpus = gaussian_mixture(n, dim, seed=seed)
+    if corpus is None:
+        corpus = gaussian_mixture(n, dim, seed=seed)
     ids = [f"vec-{i}" for i in range(n)]
     payloads = [{"shard": f"s{i % 8}"} for i in range(n)]
     col.upsert(ids, corpus, payloads)
@@ -56,6 +67,35 @@ def _recall_of(results, gt, k) -> float:
     hits = sum(len({h.id for h in r} & {f"vec-{j}" for j in t})
                for r, t in zip(results, gt))
     return hits / (len(results) * k)
+
+
+def concurrent_queries(fn: Callable[[int], Any], n: int,
+                       timeout: float = 60.0) -> List[Any]:
+    """fn(i) for i in range(n), one thread each, all in flight at once —
+    the concurrent-client load that makes the batcher coalesce.  A slot
+    stays None when its thread has not finished within `timeout`."""
+    results: List[Any] = [None] * n
+
+    def worker(i):
+        results[i] = fn(i)
+
+    threads = [threading.Thread(target=worker, args=(i,)) for i in range(n)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=timeout)
+    return results
+
+
+def serve_database(db, *, host: str = "127.0.0.1", port: int = 0,
+                   max_batch: int = 32, verbose: bool = False):
+    """Wrap a built Database in the HTTP service (not yet started)."""
+    from ..serving.http import QuantixarHTTPServer
+    from ..serving.service import QuantixarService, ServiceConfig
+
+    service = QuantixarService(
+        db, ServiceConfig(default_max_batch=max_batch))
+    return QuantixarHTTPServer(service, host=host, port=port, verbose=verbose)
 
 
 def run_embedded_demo(args) -> int:
@@ -94,18 +134,13 @@ def run_embedded_demo(args) -> int:
 
 
 def _start_server(args, port: int):
-    from ..serving.http import QuantixarHTTPServer
-    from ..serving.service import QuantixarService, ServiceConfig
-
     db, corpus = build_database(args.n, args.dim, args.index, args.quant,
                                 max_batch=args.max_batch,
                                 expansion_width=args.width)
     # warm the index so the first client query doesn't pay the build
     db["corpus"].query(gaussian_mixture(1, args.dim, seed=7)[0]).top_k(1).run()
-    service = QuantixarService(
-        db, ServiceConfig(default_max_batch=args.max_batch))
-    server = QuantixarHTTPServer(service, host=args.host, port=port,
-                                 verbose=args.verbose)
+    server = serve_database(db, host=args.host, port=port,
+                            max_batch=args.max_batch, verbose=args.verbose)
     return server, corpus
 
 
@@ -186,18 +221,10 @@ def run_smoke(args) -> int:
 
     queries = gaussian_mixture(args.requests, args.dim, seed=99)
     gt = exact_knn(queries, corpus, args.k, metric="cosine")
-    results = [None] * len(queries)
-
-    def worker(i):
-        results[i] = col.query(queries[i]).top_k(args.k).run()
 
     t0 = time.perf_counter()
-    threads = [threading.Thread(target=worker, args=(i,))
-               for i in range(len(queries))]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(timeout=60)
+    results = concurrent_queries(
+        lambda i: col.query(queries[i]).top_k(args.k).run(), len(queries))
     dt = time.perf_counter() - t0
 
     if any(r is None for r in results):
@@ -251,6 +278,7 @@ def main() -> int:
     ap.add_argument("--verbose", action="store_true",
                     help="per-request HTTP logging")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         return run_smoke(args)

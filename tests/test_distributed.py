@@ -2,7 +2,9 @@
 
 The multi-device tests run in a SUBPROCESS with
 --xla_force_host_platform_device_count=8 so the rest of the suite keeps
-seeing the real (single) device, per the dry-run isolation rule.
+seeing the real (single) device, per the dry-run isolation rule.  The child
+is pinned to the CPU backend: its 8 devices are virtual CPU devices, never
+an attached accelerator.
 """
 
 import os
@@ -15,12 +17,16 @@ import pytest
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-_SUBPROCESS_PROG = textwrap.dedent("""
+_CHILD_PRELUDE = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import numpy as np
     import jax, jax.numpy as jnp
     from repro.launch.mesh import make_local_mesh
+    mesh = make_local_mesh(data=4, model=2)
+""")
+
+_SEARCH_PROG = _CHILD_PRELUDE + textwrap.dedent("""
     from repro.distributed.search import (make_flat_search, make_pq_search,
                                           make_hamming_search)
     from repro.core import exact_knn
@@ -29,7 +35,6 @@ _SUBPROCESS_PROG = textwrap.dedent("""
     from repro.core.distances import normalize
     from repro.data.synthetic import gaussian_mixture
 
-    mesh = make_local_mesh(data=4, model=2)
     N, D, Q, K = 1600, 32, 8, 10
     x = gaussian_mixture(N, D, seed=0)
     q = gaussian_mixture(Q, D, seed=1)
@@ -65,7 +70,10 @@ _SUBPROCESS_PROG = textwrap.dedent("""
     d_loc, ids_loc = bq.search(codes_b, jnp.asarray(q), K)
     np.testing.assert_allclose(np.asarray(d_sh), np.asarray(d_loc),
                                rtol=1e-5, atol=1e-5)
+    print("SUBPROCESS_OK")
+""")
 
+_TRAIN_PROG = _CHILD_PRELUDE + textwrap.dedent("""
     # ---- model train_step lowers + runs on 4x2 mesh ----
     from repro.configs import get_smoke_config
     from repro.models import init_train_state, make_train_step
@@ -87,15 +95,36 @@ _SUBPROCESS_PROG = textwrap.dedent("""
 """)
 
 
-@pytest.mark.slow
-def test_sharded_search_multidevice():
+def _run_child(prog: str):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(_ROOT, "src") + os.pathsep + _ROOT
-    env.pop("JAX_PLATFORMS", None)
-    out = subprocess.run([sys.executable, "-c", _SUBPROCESS_PROG],
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run([sys.executable, "-c", prog],
                          capture_output=True, text=True, env=env,
                          timeout=600)
     assert "SUBPROCESS_OK" in out.stdout, out.stdout + out.stderr
+
+
+@pytest.mark.slow
+def test_sharded_search_multidevice():
+    """Exact flat, PQ-ADC and Hamming shard_map scans on a 4x2 mesh."""
+    _run_child(_SEARCH_PROG)
+
+
+@pytest.mark.slow
+def test_train_step_multidevice():
+    """LLM train step on a 4x2 mesh (the training stack's own test)."""
+    _run_child(_TRAIN_PROG)
+
+
+def test_local_mesh_refuses_missing_devices():
+    """A mesh larger than the device count raises instead of shrinking."""
+    import jax
+    from repro.launch.mesh import make_local_mesh
+    n = len(jax.devices())
+    with pytest.raises(ValueError, match="devices"):
+        make_local_mesh(data=n + 1, model=1)
+    assert make_local_mesh(data=n, model=1).devices.size == n
 
 
 class TestShardingPolicy:

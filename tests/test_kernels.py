@@ -17,7 +17,7 @@ except ImportError:      # deterministic shim keeps properties runnable
 from repro.kernels import ops, ref
 from repro.kernels.beam_gather import (beam_gather_adc_kernel,
                                        beam_gather_hamming_kernel,
-                                       beam_gather_kernel)
+                                       beam_gather_kernel, gather_table)
 from repro.kernels.hamming import hamming_kernel
 from repro.kernels.l2 import l2_distance_kernel
 from repro.kernels.pq_adc import pq_adc_kernel
@@ -133,8 +133,8 @@ class TestBeamGatherKernel:
         corpus = jnp.asarray(RNG.randn(n, d), jnp.float32)
         q = jnp.asarray(RNG.randn(d), jnp.float32)
         ids = jnp.asarray(RNG.randint(0, n, l), jnp.int32)
-        got = beam_gather_kernel(q, ids, corpus, mode=mode, tb=tb,
-                                 interpret=True)
+        got = beam_gather_kernel(q, ids, gather_table(corpus), mode=mode,
+                                 tb=tb, interpret=True)
         want = (ref.beam_gather_l2_ref(q, ids, corpus) if mode == "l2"
                 else ref.beam_gather_dot_ref(q, ids, corpus))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -145,7 +145,8 @@ class TestBeamGatherKernel:
         corpus = jnp.asarray(RNG.randn(40, 24), jnp.float32)
         q = jnp.asarray(RNG.randn(24), jnp.float32)
         ids = jnp.asarray([0, 39, 7, 7, 7, 0, 39, 13], jnp.int32)
-        got = beam_gather_kernel(q, ids, corpus, tb=4, interpret=True)
+        got = beam_gather_kernel(q, ids, gather_table(corpus), tb=4,
+                                 interpret=True)
         np.testing.assert_allclose(
             np.asarray(got),
             np.asarray(ref.beam_gather_l2_ref(q, ids, corpus)),
@@ -158,8 +159,8 @@ class TestBeamGatherKernel:
         lut = jnp.asarray(RNG.rand(m, k), jnp.float32)
         codes = jnp.asarray(RNG.randint(0, k, (n, m)), jnp.uint8)
         ids = jnp.asarray(RNG.randint(0, n, l), jnp.int32)
-        got = beam_gather_adc_kernel(lut, ids, codes, tb=16, m_chunk=4,
-                                     interpret=True)
+        got = beam_gather_adc_kernel(lut, ids, gather_table(codes), tb=16,
+                                     m_chunk=4, interpret=True)
         np.testing.assert_allclose(
             np.asarray(got),
             np.asarray(ref.beam_gather_adc_ref(lut, ids, codes)),
@@ -170,7 +171,8 @@ class TestBeamGatherKernel:
         qc = jnp.asarray(RNG.randint(0, 2 ** 31, w), jnp.uint32)
         xc = jnp.asarray(RNG.randint(0, 2 ** 31, (n, w)), jnp.uint32)
         ids = jnp.asarray(RNG.randint(0, n, l), jnp.int32)
-        got = beam_gather_hamming_kernel(qc, ids, xc, tb=16, interpret=True)
+        got = beam_gather_hamming_kernel(qc, ids, gather_table(xc), tb=16,
+                                         interpret=True)
         want = ref.beam_gather_hamming_ref(qc, ids, xc)
         assert (np.asarray(got) == np.asarray(want)).all()
 
@@ -180,13 +182,29 @@ class TestBeamGatherKernel:
         corpus = jnp.asarray(RNG.randn(60, 32), jnp.float32)
         q = jnp.asarray(RNG.randn(32), jnp.float32)
         ids = jnp.asarray(RNG.randint(0, 60, 21), jnp.int32)
+        table = gather_table(corpus)
         for mode in ("l2", "dot"):
-            a = ops.beam_gather_distances(q, ids, corpus, mode=mode,
+            a = ops.beam_gather_distances(q, ids, table, mode=mode,
                                           force_ref=True)
-            b = ops.beam_gather_distances(q, ids, corpus, mode=mode,
+            b = ops.beam_gather_distances(q, ids, table, mode=mode,
                                           force_ref=False, tb=8)
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-4, atol=2e-4)
+
+    @pytest.mark.parametrize("force_ref", [True, False])
+    def test_plain_matrix_refused(self, force_ref):
+        """Kernels and oracles take gather tables only: a plain corpus
+        would be padded and copied on every call of the search loop."""
+        corpus = jnp.asarray(RNG.randn(60, 32), jnp.float32)
+        ids = jnp.asarray(RNG.randint(0, 60, 21), jnp.int32)
+        with pytest.raises(ValueError, match="gather table"):
+            ops.beam_gather_distances(corpus[0], ids, corpus,
+                                      force_ref=force_ref)
+        codes = gather_table(jnp.asarray(RNG.randint(0, 16, (60, 8)),
+                                         jnp.int32))
+        with pytest.raises(ValueError, match="gather table"):
+            ops.beam_gather_hamming(jnp.zeros((8,), jnp.uint32), ids, codes,
+                                    force_ref=force_ref)
 
 
 class TestPairGatherKernel:
@@ -203,7 +221,8 @@ class TestPairGatherKernel:
         from repro.kernels.bulk_prune import pair_gather_kernel
         corpus = jnp.asarray(RNG.randn(n, d), jnp.float32)
         ids = jnp.asarray(RNG.randint(0, n, c), jnp.int32)
-        got = pair_gather_kernel(ids, corpus, mode=mode, interpret=True)
+        got = pair_gather_kernel(ids, gather_table(corpus), mode=mode,
+                                 interpret=True)
         want = (ref.pair_gather_l2_ref(ids, corpus) if mode == "l2"
                 else ref.pair_gather_dot_ref(ids, corpus))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -213,7 +232,8 @@ class TestPairGatherKernel:
         from repro.kernels.bulk_prune import pair_gather_kernel
         corpus = jnp.asarray(RNG.randn(30, 24), jnp.float32)
         ids = jnp.asarray([5, 5, 0, 29, 5], jnp.int32)
-        got = np.asarray(pair_gather_kernel(ids, corpus, interpret=True))
+        got = np.asarray(pair_gather_kernel(ids, gather_table(corpus),
+                                            interpret=True))
         assert got.shape == (5, 5)
         np.testing.assert_allclose(np.diag(got), 0.0, atol=1e-4)
         np.testing.assert_allclose(got[0, 1], 0.0, atol=1e-4)  # dup pair
@@ -221,10 +241,11 @@ class TestPairGatherKernel:
     def test_ops_dispatch_parity(self):
         corpus = jnp.asarray(RNG.randn(60, 32), jnp.float32)
         ids = jnp.asarray(RNG.randint(0, 60, 21), jnp.int32)
+        table = gather_table(corpus)
         for mode in ("l2", "dot"):
-            a = ops.pair_gather_distances(ids, corpus, mode=mode,
+            a = ops.pair_gather_distances(ids, table, mode=mode,
                                           force_ref=True)
-            b = ops.pair_gather_distances(ids, corpus, mode=mode,
+            b = ops.pair_gather_distances(ids, table, mode=mode,
                                           force_ref=False)
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-4, atol=2e-4)

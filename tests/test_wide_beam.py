@@ -94,16 +94,25 @@ class TestWideBeamTraversal:
 
     def test_width1_bitforbit_matches_seed_loop(self, packed, queries):
         """The seed's single-pop loop, re-implemented verbatim, must equal
-        width=1 of the wide-beam loop — distances and ids exactly."""
+        width=1 of the wide-beam loop — distances and ids exactly.  The seed
+        loop reads the proxy rows out of the shipped gather table and takes
+        queries zero-padded to its lane width, as search() does."""
         g, ml, metric = to_device(packed)
         ef, k = 48, 10
         max_iters = 4 * ef
         n = g.vectors.shape[0]
         n_words = (n + 31) // 32
+        vecs = g.vectors[:, 0, :]              # (N, Dp)
+
+        def dist_rows(q, rows):                # the seed's row distances
+            if metric == "l2":
+                d = rows - q[None, :]
+                return jnp.sum(d * d, axis=-1)
+            return -jnp.dot(rows, q, precision=jax.lax.Precision.HIGHEST)
 
         def seed_beam(q, ep_global):           # seed _beam_search_base
             cand_d = jnp.full((ef,), jnp.inf).at[0].set(
-                hs._dist_rows(q, g.vectors[ep_global][None, :], metric)[0])
+                dist_rows(q, vecs[ep_global][None, :])[0])
             cand_id = jnp.full((ef,), -1, dtype=jnp.int32).at[0].set(
                 ep_global)
             expanded = jnp.zeros((ef,), dtype=bool)
@@ -132,8 +141,8 @@ class TestWideBeamTraversal:
                 add_val = jnp.where(fresh, jnp.uint32(1) << bit,
                                     jnp.uint32(0))
                 visited = visited.at[word].add(add_val)
-                rows = g.vectors[safe]
-                d = jnp.where(fresh, hs._dist_rows(q, rows, metric), jnp.inf)
+                rows = vecs[safe]
+                d = jnp.where(fresh, dist_rows(q, rows), jnp.inf)
                 new_id = jnp.where(fresh, nbrs, -1)
                 merged_d = jnp.concatenate([cand_d, d])
                 merged_id = jnp.concatenate([cand_id, new_id])
@@ -149,19 +158,16 @@ class TestWideBeamTraversal:
 
         @jax.jit
         def seed_search(qs):                   # seed search(), ml/metric fixed
-            def one(q):
-                slot = g.entry_upper
-                for layer in range(ml, 0, -1):
-                    slot = hs._descend(q, g, layer - 1, slot, metric)
-                ep = jnp.where(jnp.asarray(ml > 0),
-                               g.upper_ids[slot], g.entry_global)
+            def one(q, ep):
                 d, ids = seed_beam(q, ep)
                 return d[:k], ids[:k]
 
-            return jax.vmap(one)(qs)
+            # the layer-0 entry rule is shared; the loops under test differ
+            return jax.vmap(one)(qs, hs._entry_points(g, qs, ml, metric))
 
         qn = jnp.asarray(preprocess_vectors(queries, "cosine"))
-        d_seed, ids_seed = seed_search(qn)
+        d_seed, ids_seed = seed_search(
+            jnp.pad(qn, ((0, 0), (0, vecs.shape[1] - qn.shape[1]))))
         d_new, ids_new = search(g, qn, k=k, ef=ef, max_level=ml,
                                 metric=metric, expansion_width=1)
         assert (np.asarray(ids_seed) == np.asarray(ids_new)).all()
@@ -278,12 +284,20 @@ class TestQuantizedWideBeam:
         assert calls["float"] > 0
 
     def test_graph_ships_codes(self, corpus):
-        for quant, dtype in (("pq", np.uint8), ("bq", np.uint32)):
+        """Codes ship as a gather table: (N, 1, 128k) with 8-bit PQ codes
+        widened to int32, lanes past the code width zero, rows unchanged."""
+        for quant, dtype in (("pq", np.int32), ("bq", np.uint32)):
             eng = _quantized_engine(corpus, quant)
             g = eng._device_graph[0]
             assert g.codes is not None
             assert g.codes.dtype == dtype
-            assert g.codes.shape[0] == eng._packed.n
+            n, one, wp = g.codes.shape
+            assert (n, one, wp % 128) == (eng._packed.n, 1, 0)
+            codes = np.asarray(eng._codes)
+            table = np.asarray(g.codes)[:, 0, :]
+            w = codes.shape[1]
+            assert (table[:, :w] == codes[:n]).all()
+            assert (table[:, w:] == 0).all()
 
 
 # ---------------------------------------------------------------------------
