@@ -1,0 +1,7 @@
+"""Seconds of the bulk HNSW build's candidates phase, as the builder times it on
+the host (``build_s_candidates`` in the collection's stats after the build)."""
+
+
+def read(run):
+    value = run.build_info.get("build_s_candidates")
+    return None if value is None else float(value)
