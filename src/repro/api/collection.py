@@ -29,6 +29,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from .. import tracing
 from ..core.engine import QuantixarEngine
 from ..core.executor import AnnParams, ExecResult, PlanExecutor
 from ..core.metadata import Filter
@@ -317,19 +318,26 @@ class Collection:
         """One masked first-pass engine search — the ANN primitive both the
         serving batcher and the plan executor call.  Per-query knobs arrive
         as a single `AnnParams` struct instead of parallel keyword lists."""
-        with self._lock:
-            if len(self._row_of) == 0:
-                # empty collection = empty result, not an error: pad with
-                # the engine's masked-slot convention (inf distance, row -1)
-                if k < 1:
-                    raise ValueError(f"k must be >= 1, got {k}")
-                n = 1 if queries.ndim == 1 else len(queries)
-                return (np.full((n, k), np.inf, dtype=np.float32),
-                        np.full((n, k), -1, dtype=np.int64))
-            k = min(k, len(self._row_of))
-            return self._engine.search(queries, k, flt=flt,
-                                       mask=self._live_mask(),
-                                       params=params)
+        with tracing.span("engine.lock"):
+            self._lock.acquire()
+        try:
+            return self._engine_search_held(queries, k, flt, params)
+        finally:
+            self._lock.release()
+
+    def _engine_search_held(self, queries, k, flt,  # requires-lock: _lock
+                            params: Optional[AnnParams]):
+        if len(self._row_of) == 0:
+            # empty collection = empty result, not an error: pad with
+            # the engine's masked-slot convention (inf distance, row -1)
+            if k < 1:
+                raise ValueError(f"k must be >= 1, got {k}")
+            n = 1 if queries.ndim == 1 else len(queries)
+            return (np.full((n, k), np.inf, dtype=np.float32),
+                    np.full((n, k), -1, dtype=np.int64))
+        k = min(k, len(self._row_of))
+        return self._engine.search(queries, k, flt=flt,
+                                   mask=self._live_mask(), params=params)
 
     def _sparse_search(self, field: str, text: str, k: int,
                        flt: Optional[Filter] = None, stats=None
@@ -470,23 +478,29 @@ class Collection:
         stage itself is not interrupted).  With `explain=True` the result
         is a `PlanExplain` carrying the compiled plan, per-stage candidate
         counts/timings, and hits."""
-        plan = validate_plan(self.schema, plan)
-        if plan.trivial and not plan.batched and not explain:
+        with tracing.span("api.plan"):
+            plan = validate_plan(self.schema, plan)
+            single = plan.trivial and not plan.batched and not explain
+            if single:
+                stage = plan.stages[0]
+                vec = np.asarray(plan.vector, dtype=np.float32)
+                params = AnnParams.or_none(
+                    ef=stage.ef, expansion_width=stage.expansion_width,
+                    rescore=stage.rescore)
+        if single:
             # single query: coalesce through the serving batcher.  The
             # future resolves outside the lock, so a concurrent compact()
             # could renumber rows before translation — detect via the epoch
             # and retry.
-            stage = plan.stages[0]
-            vec = np.asarray(plan.vector, dtype=np.float32)
-            params = AnnParams.or_none(ef=stage.ef,
-                                       expansion_width=stage.expansion_width,
-                                       rescore=stage.rescore)
             for _ in range(5):
-                epoch = self._epoch  # unguarded-ok: optimistic read, re-validated under _lock below
-                fut = self.batcher.submit(vec, plan.k, flt=stage.filter,
-                                          params=params)
+                with tracing.span("api.plan"):
+                    epoch = self._epoch  # unguarded-ok: optimistic read, re-validated under _lock below
+                    fut = self.batcher.submit(vec, plan.k,
+                                              flt=stage.filter,
+                                              params=params)
                 d, rows = fut.result(timeout=timeout)
-                with self._lock:
+                tracing.answered_by(fut.batch)
+                with tracing.span("api.hits"), self._lock:
                     if self._epoch == epoch:
                         return self._hits_for(d, rows, include_vector)
             raise QueryRetriesExhausted(
@@ -494,13 +508,14 @@ class Collection:
         deadline = time.perf_counter() + timeout
         with self._lock:   # rows stay valid until translated to ids
             res = self._execute_direct(plan, deadline=deadline)
-            if plan.batched:
-                hits: Any = [self._hits_for(res.distances[i], res.ids[i],
-                                            include_vector)
-                             for i in range(len(res.ids))]
-            else:
-                hits = self._hits_for(res.distances[0], res.ids[0],
-                                      include_vector)
+            with tracing.span("api.hits"):
+                if plan.batched:
+                    hits: Any = [self._hits_for(res.distances[i],
+                                                res.ids[i], include_vector)
+                                 for i in range(len(res.ids))]
+                else:
+                    hits = self._hits_for(res.distances[0], res.ids[0],
+                                          include_vector)
         if explain:
             return PlanExplain(plan=plan_to_dict(plan), stages=res.stages,
                                hits=hits)

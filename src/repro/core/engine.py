@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from . import bq as bq_mod
 from . import pq as pq_mod
 from .distances import get_metric
@@ -106,7 +107,11 @@ class QuantixarEngine:
         self._delta: Optional[DeltaSegment] = None  # exists once sealed
         self._delta_cache = None    # (delta, version, eff_device, metric)
         self.build_seconds: float = 0.0
-        self.insert_seconds: float = 0.0
+        # search-path counters (the caller's lock serializes searches)
+        self.h2d_bytes = 0          # host-to-device bytes uploaded
+        self.hnsw_trips = 0         # layer-0 traversal trips, real queries
+        self.hnsw_queries = 0       # real queries the traversal answered
+        self.flat_fallbacks = 0     # masked beams that under-delivered
         # observability for the segmented write path: a post-build add() must
         # bump none of these; seal() bumps seal/index, never quantizer_trains
         self.index_builds = 0       # HNSW-graph / IVF-list constructions
@@ -149,7 +154,6 @@ class QuantixarEngine:
         sealed graph is untouched, and the rows are immediately searchable
         via the exact delta scan.  The seal policy may then fold the delta.
         """
-        t0 = time.perf_counter()
         vectors = np.asarray(vectors, dtype=np.float32)
         if vectors.ndim != 2 or vectors.shape[1] != self.config.dim:
             raise ValueError(
@@ -171,7 +175,6 @@ class QuantixarEngine:
             if self.config.seal.auto and self.config.seal.should_seal(
                     self._sealed_n, len(self._delta)):
                 self.seal()
-        self.insert_seconds += time.perf_counter() - t0
 
     def _encode(self, vectors: np.ndarray) -> Optional[np.ndarray]:
         """Encode-only against trained codebooks (never retrains)."""
@@ -333,35 +336,42 @@ class QuantixarEngine:
 
         Returns (distances (Q,k) in the engine metric, ids (Q,k); -1 = none).
         """
-        if params is not None:
-            if (ef, rescore, expansion_width) != (None, None, None):
-                raise ValueError(
-                    "pass ef/rescore/expansion_width either as keywords or "
-                    "inside params=AnnParams(...), not both")
-            ef, rescore = params.ef, params.rescore
-            expansion_width = params.expansion_width
-        if k < 1:
-            raise ValueError(f"k must be >= 1, got {k}")
-        if self._dirty:
-            self.build()
-        cfg = self.config
-        queries = np.asarray(queries, dtype=np.float32)
-        if queries.ndim == 1:
-            queries = queries[None, :]
-        # `ef or ...` would silently turn an explicit ef=0 into the default
-        ef = ef if ef is not None else max(cfg.ef_search, k)
-        flt_mask = self.metadata.evaluate(flt) if flt is not None else None
-        if mask is not None:
-            mask = np.asarray(mask, dtype=bool)
-            mask = flt_mask & mask if flt_mask is not None else mask
-        else:
-            mask = flt_mask
+        with tracing.span("engine.prep"):
+            if params is not None:
+                if (ef, rescore, expansion_width) != (None, None, None):
+                    raise ValueError(
+                        "pass ef/rescore/expansion_width either as keywords "
+                        "or inside params=AnnParams(...), not both")
+                ef, rescore = params.ef, params.rescore
+                expansion_width = params.expansion_width
+            if k < 1:
+                raise ValueError(f"k must be >= 1, got {k}")
+            if self._dirty:
+                self.build()
+            cfg = self.config
+            queries = np.asarray(queries, dtype=np.float32)
+            if queries.ndim == 1:
+                queries = queries[None, :]
+            # `ef or ...` would silently turn an explicit ef=0 into the
+            # default
+            ef = ef if ef is not None else max(cfg.ef_search, k)
+        to_flat = cfg.index == "flat"
+        if flt is not None or mask is not None:
+            with tracing.span("engine.filter", rows=self._n):
+                flt_mask = (self.metadata.evaluate(flt) if flt is not None
+                            else None)
+                if mask is not None:
+                    mask = np.asarray(mask, dtype=bool)
+                    mask = flt_mask & mask if flt_mask is not None else mask
+                else:
+                    mask = flt_mask
+                to_flat = to_flat or self._route_to_flat(mask)
         do_rescore = cfg.rescore if rescore is None else rescore
         do_rescore = do_rescore and cfg.quantization != "none"
 
         fetch = k * cfg.rescore_multiplier if do_rescore else k
 
-        if cfg.index == "flat" or self._route_to_flat(mask):
+        if to_flat:
             # the flat scan covers the whole corpus (delta rows included:
             # their codes were appended at insert time)
             d, ids = self._flat_pass(queries, fetch, mask)
@@ -373,17 +383,26 @@ class QuantixarEngine:
                                          expansion_width)
             if self.delta_rows:
                 dd, dids = self._delta_pass(queries, fetch, mask)
-                d, ids = merge_candidates(d, ids, dd, dids, fetch)
-            if mask is not None and (ids[:, : min(fetch, ids.shape[1])] == -1).any():
-                # beam under-delivered under the filter: exact masked scan
+                with tracing.span("engine.post"):
+                    d, ids = merge_candidates(d, ids, dd, dids, fetch)
+            fallback = False
+            if mask is not None:
+                with tracing.span("engine.post") as post:
+                    # a beam that under-delivered under the filter falls
+                    # back to the exact masked scan
+                    fallback = bool(
+                        (ids[:, : min(fetch, ids.shape[1])] == -1).any())
+                    post.set(fallback=int(fallback))
+            if fallback:
+                self.flat_fallbacks += 1
                 d, ids = self._flat_pass(queries, fetch, mask)
 
         if do_rescore:
             d, ids = self.exact_rescore(queries, ids, k, mask=mask)
-        else:
+        with tracing.span("engine.post"):
             d, ids = d[:, :k], ids[:, :k]
-        # contract: +inf slots (masked-out / padded) never expose a row id
-        return d, np.where(np.isfinite(d), ids, -1)
+            # contract: +inf slots (masked-out / padded) never expose a row
+            return d, np.where(np.isfinite(d), ids, -1)
 
     def _route_to_flat(self, mask: Optional[np.ndarray]) -> bool:
         """MEVS routing (paper: filter first, then search the subset): at low
@@ -393,29 +412,54 @@ class QuantixarEngine:
         sel = mask.mean() if len(mask) else 0.0
         return sel <= self.config.filter_flat_threshold
 
+    def _h2d(self, x) -> jax.Array:
+        """Upload one host array for a search pass.  Every upload of the
+        search path comes through here, so that its bytes are counted."""
+        x = np.asarray(x)
+        with tracing.span("engine.h2d", bytes=x.nbytes):
+            out = jnp.asarray(x)
+        self.h2d_bytes += x.nbytes
+        return out
+
+    @staticmethod
+    def _real(n: int) -> int:
+        """Real queries of an ``n``-row pass: a served batch is padded."""
+        real = tracing.REAL_QUERIES.get()
+        return n if real is None else min(real, n)
+
+    def _device(self, name: str, n: int):
+        """Span of one jitted pass over ``n`` queries: its dispatch and one
+        fetch of all its outputs."""
+        return tracing.span("engine.device", queries=self._real(n),
+                            **{"pass": name})
+
     def _flat_pass(self, queries, k, mask):
         cfg = self.config
-        mask_j = None if mask is None else jnp.asarray(mask)
-        if cfg.quantization == "pq":
-            lut = pq_mod.build_adc_lut(
-                jnp.asarray(queries), self._pq.codebooks,
-                normalize_inputs=cfg.metric == "cosine")
-            d = pq_mod.adc_distances(lut, jnp.asarray(self._codes))
-            if mask_j is not None:
-                d = jnp.where(mask_j[None, :], d, jnp.inf)
-            neg_top, idx = jax.lax.top_k(-d, min(k, d.shape[1]))
-            return np.asarray(-neg_top), np.asarray(idx, dtype=np.int32)
-        if cfg.quantization == "bq":
-            q_codes = self._bq.encode(jnp.asarray(queries))
-            d = bq_mod.hamming_distances(q_codes, jnp.asarray(self._codes))
-            d = d.astype(jnp.float32)
-            if mask_j is not None:
-                d = jnp.where(mask_j[None, :], d, jnp.inf)
-            neg_top, idx = jax.lax.top_k(-d, min(k, d.shape[1]))
-            return np.asarray(-neg_top), np.asarray(idx, dtype=np.int32)
-        d, ids = flat_search(jnp.asarray(queries), jnp.asarray(self.vectors),
-                             min(k, self._n), metric=cfg.metric, mask=mask_j)
-        return np.asarray(d), np.asarray(ids)
+        mask_j = None if mask is None else self._h2d(mask)
+        q = self._h2d(queries)
+        if cfg.quantization in ("pq", "bq"):
+            codes = self._h2d(self._codes)
+            with self._device("flat", len(queries)):
+                if cfg.quantization == "pq":
+                    lut = pq_mod.build_adc_lut(
+                        q, self._pq.codebooks,
+                        normalize_inputs=cfg.metric == "cosine")
+                    d = pq_mod.adc_distances(lut, codes)
+                else:
+                    d = bq_mod.hamming_distances(self._bq.encode(q), codes)
+                    d = d.astype(jnp.float32)
+                if mask_j is not None:
+                    d = jnp.where(mask_j[None, :], d, jnp.inf)
+                neg_top, idx = jax.device_get(
+                    jax.lax.top_k(-d, min(k, d.shape[1])))
+                del q, codes, mask_j, d     # freed inside the pass's span
+            return -neg_top, idx.astype(np.int32)
+        corpus = self._h2d(self.vectors)
+        with self._device("flat", len(queries)):
+            out = jax.device_get(flat_search(q, corpus, min(k, self._n),
+                                             metric=cfg.metric, mask=mask_j))
+            del q, corpus, mask_j       # the pass's uploads are freed here
+        return out
 
     def _hnsw_pass(self, queries, k, ef, mask, expansion_width=None):
         """Wide-beam-search the sealed graph only (delta rows merge
@@ -434,11 +478,15 @@ class QuantixarEngine:
         q = queries
         q_codes = None
         if metric == "dot" and cfg.quantization == "none":
-            q = preprocess_vectors(queries, cfg.metric)
+            with tracing.span("engine.prep"):
+                q = preprocess_vectors(queries, cfg.metric)
         elif cfg.quantization == "bq":
-            packed_q = self._bq.encode(jnp.asarray(queries))   # (Q, W) uint32
-            signs = np.asarray(bq_mod.unpack_bits(packed_q, cfg.bq.bits),
-                               dtype=np.float32)
+            raw_q = self._h2d(queries)
+            with self._device("encode", len(queries)):
+                packed_q = self._bq.encode(raw_q)        # (Q, W) uint32
+                signs = np.asarray(bq_mod.unpack_bits(packed_q,
+                                                      cfg.bq.bits),
+                                   dtype=np.float32)
             q = signs * 2.0 - 1.0            # descent proxy (±1 sign vectors)
             if g.codes is not None:
                 metric = "hamming"
@@ -449,19 +497,28 @@ class QuantixarEngine:
             if g.codes is not None:
                 metric = "adc"
                 q_codes = pq_mod.build_adc_lut(
-                    jnp.asarray(queries), self._pq.codebooks,
+                    self._h2d(queries), self._pq.codebooks,
                     normalize_inputs=cfg.metric == "cosine")
-        d, ids = hnsw_search(g, jnp.asarray(q), k=min(ef_eff, n_sealed),
-                             ef=min(ef_eff, n_sealed), max_level=max_level,
-                             metric=metric, expansion_width=width,
-                             q_codes=q_codes)
-        d, ids = np.asarray(d), np.asarray(ids)
-        if metric == "hamming":
-            # back to the -dot space the delta scan / merge uses:
-            # dot(±1) = bits - 2·hamming, so -dot = 2·hamming - bits (exact)
-            d = np.where(np.isfinite(d), 2.0 * d - float(cfg.bq.bits), d)
-        d, ids = self._apply_mask(d, ids, mask, n_sealed)
-        return d[:, :k], ids[:, :k]
+        q = self._h2d(q)
+        with self._device("hnsw", len(queries)) as dev:
+            d, ids, iters = jax.device_get(hnsw_search(
+                g, q, k=min(ef_eff, n_sealed), ef=min(ef_eff, n_sealed),
+                max_level=max_level, metric=metric, expansion_width=width,
+                q_codes=q_codes, with_iters=True))
+            real = self._real(len(queries))
+            trips = int(iters[:real].sum())
+            dev.set(trips=trips)
+        self.hnsw_trips += trips
+        self.hnsw_queries += real
+        with tracing.span("engine.post"):
+            if metric == "hamming":
+                # back to the -dot space the delta scan / merge uses:
+                # dot(±1) = bits - 2·hamming, so -dot = 2·hamming - bits
+                # (exact)
+                d = np.where(np.isfinite(d), 2.0 * d - float(cfg.bq.bits),
+                             d)
+            d, ids = self._apply_mask(d, ids, mask, n_sealed)
+            return d[:, :k], ids[:, :k]
 
     def effective_expansion_width(self, override: Optional[int] = None) -> int:
         """Per-query override > EngineConfig.expansion_width > HNSWConfig."""
@@ -475,11 +532,12 @@ class QuantixarEngine:
 
     def _ivf_pass(self, queries, k, mask):
         """Probe the sealed IVF lists only (delta rows merge separately)."""
-        d, ids = self._ivf.search(jnp.asarray(self._ivf_effective),
-                                  jnp.asarray(queries), k)
-        d, ids = self._apply_mask(np.asarray(d), np.asarray(ids),
-                                  mask, self._sealed_n)
-        return d[:, :k], ids[:, :k]
+        eff, q = self._h2d(self._ivf_effective), self._h2d(queries)
+        with self._device("ivf", len(queries)):
+            d, ids = jax.device_get(self._ivf.search(eff, q, k))
+        with tracing.span("engine.post"):
+            d, ids = self._apply_mask(d, ids, mask, self._sealed_n)
+            return d[:, :k], ids[:, :k]
 
     @staticmethod
     def _apply_mask(d, ids, mask, n_rows):
@@ -515,14 +573,18 @@ class QuantixarEngine:
         n_d = len(delta)
         eff_dev, metric = self._delta_effective()
         if cfg.index == "ivf":
-            q = np.asarray(self._ivf._prep(jnp.asarray(queries)))
+            raw_q = self._h2d(queries)
+            with self._device("encode", len(queries)):
+                q = np.asarray(self._ivf._prep(raw_q))
         elif cfg.quantization == "pq":
             q = preprocess_vectors(queries, "cosine") \
                 if cfg.metric == "cosine" else queries
         elif cfg.quantization == "bq":
-            q = np.asarray(bq_mod.unpack_bits(
-                self._bq.encode(jnp.asarray(queries)), cfg.bq.bits),
-                dtype=np.float32) * 2.0 - 1.0
+            raw_q = self._h2d(queries)
+            with self._device("encode", len(queries)):
+                q = np.asarray(bq_mod.unpack_bits(
+                    self._bq.encode(raw_q), cfg.bq.bits),
+                    dtype=np.float32) * 2.0 - 1.0
         else:
             q = preprocess_vectors(queries, cfg.metric)
         padded = int(eff_dev.shape[0])
@@ -530,10 +592,12 @@ class QuantixarEngine:
                 else np.asarray(mask[delta.start:], dtype=bool))
         if padded > n_d:
             live = np.concatenate([live, np.zeros(padded - n_d, dtype=bool)])
-        d, ids = flat_search(jnp.asarray(q), eff_dev, min(k, padded),
-                             metric=metric, mask=jnp.asarray(live),
-                             base_index=delta.start)
-        return np.asarray(d), np.asarray(ids, dtype=np.int32)
+        q, live = self._h2d(q), self._h2d(live)
+        with self._device("delta", len(queries)):
+            d, ids = jax.device_get(flat_search(
+                q, eff_dev, min(k, padded), metric=metric, mask=live,
+                base_index=delta.start))
+        return d, ids.astype(np.int32)
 
     def _delta_effective(self):
         """Device-resident distance-space matrix for the delta scan, padded
@@ -568,7 +632,7 @@ class QuantixarEngine:
         if padded > n_d:
             eff = np.concatenate(
                 [eff, np.zeros((padded - n_d, eff.shape[1]), eff.dtype)])
-        eff_dev = jnp.asarray(eff)
+        eff_dev = self._h2d(eff)
         self._delta_cache = (delta, delta.version, eff_dev, metric)
         return eff_dev, metric
 
@@ -579,21 +643,24 @@ class QuantixarEngine:
         re-applied here: exact distances would otherwise resurrect
         masked-out candidates that the first pass only demoted to +inf."""
         pair = get_metric(self.config.metric)
-        raw = self.vectors
-        safe = np.maximum(cand_ids, 0)
-        cand_vecs = raw[safe]                      # (Q, k', D)
-        d = np.stack([
-            np.asarray(pair(jnp.asarray(queries[i: i + 1]),
-                            jnp.asarray(cand_vecs[i])))[0]
-            for i in range(len(queries))])
-        ok = cand_ids >= 0
-        if mask is not None:
-            ok &= mask[safe]
-        d = np.where(ok, d, np.inf)
-        order = np.argsort(d, axis=1, kind="stable")[:, :k]
-        d = np.take_along_axis(d, order, axis=1)
-        ids = np.take_along_axis(cand_ids, order, axis=1)
-        return d, np.where(np.isfinite(d), ids, -1)
+        with tracing.span("engine.post"):
+            safe = np.maximum(cand_ids, 0)
+            cand_vecs = self.vectors[safe]             # (Q, k', D)
+        n = len(queries)
+        q = [self._h2d(queries[i: i + 1]) for i in range(n)]
+        cand = [self._h2d(cand_vecs[i]) for i in range(n)]
+        with self._device("rescore", n):
+            d = np.stack([r[0] for r in jax.device_get(
+                [pair(q[i], cand[i]) for i in range(n)])])
+        with tracing.span("engine.post"):
+            ok = cand_ids >= 0
+            if mask is not None:
+                ok &= mask[safe]
+            d = np.where(ok, d, np.inf)
+            order = np.argsort(d, axis=1, kind="stable")[:, :k]
+            d = np.take_along_axis(d, order, axis=1)
+            ids = np.take_along_axis(cand_ids, order, axis=1)
+            return d, np.where(np.isfinite(d), ids, -1)
 
     # ----------------------------------------------------------- persistence
     def state_dict(self) -> Dict[str, Any]:
@@ -687,7 +754,10 @@ class QuantixarEngine:
                "quantization": self.config.quantization,
                "metric": self.config.metric,
                "build_seconds": self.build_seconds,
-               "insert_seconds": self.insert_seconds,
+               "h2d_bytes": self.h2d_bytes,
+               "hnsw_trips": self.hnsw_trips,
+               "hnsw_queries": self.hnsw_queries,
+               "flat_fallbacks": self.flat_fallbacks,
                "sealed_rows": self._sealed_n,
                "delta_rows": self.delta_rows,
                "index_builds": self.index_builds,

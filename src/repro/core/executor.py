@@ -26,9 +26,9 @@ single struct that carries per-query search knobs through the collection
 plumbing and into `QuantixarEngine.search` — lives here for the same
 reason.
 
-Every stage execution is timed and counted; `ExecResult.stages` is the
-per-stage report `Query.explain()` surfaces (candidate counts in/out,
-seconds, nested prefetch children).
+Every stage execution is timed (a ``plan.stage`` span) and counted;
+`ExecResult.stages` is the per-stage report `Query.explain()` surfaces
+(candidate counts in/out, seconds, nested prefetch children).
 """
 
 from __future__ import annotations
@@ -38,6 +38,8 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
+
+from .. import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -189,35 +191,35 @@ class PlanExecutor:
                     f"plan exceeded its deadline before stage "
                     f"{stage.op!r}")
             cand_in = 0 if cand is None else _valid_count(*cand)
-            t0 = time.perf_counter()
             children: Optional[List[List[Dict[str, Any]]]] = None
-            if stage.op == "ann":
-                cand = self._run_ann(stage, queries)
-            elif stage.op == "sparse":
-                cand = self._run_sparse(stage)
-            elif stage.op == "rescore":
-                cand = self._run_rescore(stage, queries, cand)
-            elif stage.op == "prefetch":
-                prefetched = [self.execute(sub, inherited=queries,
-                                           deadline=deadline)
-                              for sub in stage.plans]
-                cand_in = 0
-                cand = None
-                children = [r.stages for r in prefetched]
-            elif stage.op == "fusion":
-                cand = self._run_fusion(stage, prefetched)
-                cand_in = sum(_valid_count(r.distances, r.ids)
-                              for r in (prefetched or []))
-                prefetched = None
-            else:                     # validate_plan rejects this earlier
-                raise ValueError(f"unknown plan stage op {stage.op!r}")
+            with tracing.span("plan.stage", op=stage.op) as timed:
+                if stage.op == "ann":
+                    cand = self._run_ann(stage, queries)
+                elif stage.op == "sparse":
+                    cand = self._run_sparse(stage)
+                elif stage.op == "rescore":
+                    cand = self._run_rescore(stage, queries, cand)
+                elif stage.op == "prefetch":
+                    prefetched = [self.execute(sub, inherited=queries,
+                                               deadline=deadline)
+                                  for sub in stage.plans]
+                    cand_in = 0
+                    cand = None
+                    children = [r.stages for r in prefetched]
+                elif stage.op == "fusion":
+                    cand = self._run_fusion(stage, prefetched)
+                    cand_in = sum(_valid_count(r.distances, r.ids)
+                                  for r in (prefetched or []))
+                    prefetched = None
+                else:                 # validate_plan rejects this earlier
+                    raise ValueError(f"unknown plan stage op {stage.op!r}")
             report: Dict[str, Any] = {
                 "stage": stage.op,
                 "k": int(getattr(stage, "k", 0) or 0),
                 "candidates_in": cand_in,
                 "candidates_out": (0 if cand is None
                                    else _valid_count(*cand)),
-                "seconds": time.perf_counter() - t0,
+                "seconds": timed.seconds,
             }
             if children is not None:
                 report["candidates_out"] = sum(

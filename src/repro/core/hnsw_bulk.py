@@ -46,13 +46,13 @@ from __future__ import annotations
 
 import functools
 import logging
-import time
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..kernels import ops
 from ..kernels.beam_gather import gather_table
 from .flat import flat_search
@@ -583,92 +583,107 @@ def _bulk_coarse(vecs: np.ndarray, corpus_dev: jax.Array, cfg: HNSWConfig,
                  rng: np.random.RandomState, levels: np.ndarray, graph_meta,
                  mode: str, progress: Optional[ProgressFn]
                  ) -> Tuple[np.ndarray, np.ndarray, Dict]:
-    _, max_level, _ = graph_meta
     n, _ = vecs.shape
     m0 = cfg.m0
     r = min(cfg.M, 8, n - 1)
 
-    t0 = time.perf_counter()
-    cand_i, cand_d, margin, nlist = _coarse_candidates(
-        vecs, corpus_dev, cfg, rng, mode, progress)
-    t_cand = time.perf_counter()
-    if r > 0:
-        rnd = rng.randint(0, n, size=(n, r)).astype(np.int32)
-        cand_i = np.concatenate([cand_i, rnd], axis=1)
-        cand_d = np.concatenate(
-            [cand_d, _rowwise_dists(vecs, np.arange(n), rnd, mode)], axis=1)
+    seconds: Dict[str, float] = {}
+    with tracing.span("build.candidates") as phase:
+        cand_i, cand_d, margin, nlist = _coarse_candidates(
+            vecs, corpus_dev, cfg, rng, mode, progress)
+    seconds["candidates"] = phase.seconds
+    with tracing.span("build.prune") as phase:
+        if r > 0:
+            rnd = rng.randint(0, n, size=(n, r)).astype(np.int32)
+            cand_i = np.concatenate([cand_i, rnd], axis=1)
+            cand_d = np.concatenate(
+                [cand_d, _rowwise_dists(vecs, np.arange(n), rnd, mode)],
+                axis=1)
 
-    all_ids = np.arange(n, dtype=np.int32)
-    sel_i, sel_d, sel_p = _prune_chunks(corpus_dev, all_ids, cand_i, cand_d,
-                                        m=m0, mode=mode,
-                                        keep_pruned=cfg.keep_pruned)
-    t_prune = time.perf_counter()
-    if progress is not None:
-        progress("prune", n, n)
+        all_ids = np.arange(n, dtype=np.int32)
+        sel_i, sel_d, sel_p = _prune_chunks(corpus_dev, all_ids, cand_i,
+                                            cand_d, m=m0, mode=mode,
+                                            keep_pruned=cfg.keep_pruned)
+        if progress is not None:
+            progress("prune", n, n)
+    seconds["prune"] = phase.seconds
 
-    adj = jnp.full((n + 1, m0), PAD, jnp.int32)
-    adj_d = jnp.full((n + 1, m0), jnp.inf, jnp.float32)
-    adj_p = jnp.ones((n + 1, m0), jnp.int32)
-    tgt, src, dd, pp = _edges_both_ways(sel_i, sel_d, sel_p, all_ids)
-    adj, adj_d, adj_p = _merge_cap(
-        adj, adj_d, adj_p, jnp.asarray(tgt), jnp.asarray(src),
-        jnp.asarray(dd), jnp.asarray(pp), m=m0)
-    adj.block_until_ready()
-    t_merge = time.perf_counter()
+    with tracing.span("build.merge") as phase:
+        adj = jnp.full((n + 1, m0), PAD, jnp.int32)
+        adj_d = jnp.full((n + 1, m0), jnp.inf, jnp.float32)
+        adj_p = jnp.ones((n + 1, m0), jnp.int32)
+        tgt, src, dd, pp = _edges_both_ways(sel_i, sel_d, sel_p, all_ids)
+        adj, adj_d, adj_p = jax.block_until_ready(_merge_cap(
+            adj, adj_d, adj_p, jnp.asarray(tgt), jnp.asarray(src),
+            jnp.asarray(dd), jnp.asarray(pp), m=m0))
+    seconds["merge"] = phase.seconds
 
     # ---- cross-cluster stitching: boundary nodes re-search the built graph
     n_stitch = int(round(cfg.stitch_frac * n)) if nlist > 1 else 0
-    if n_stitch > 0:
-        ef_st = max(min(cfg.ef_build or STITCH_EF, STITCH_EF), cfg.M)
-        k_st = min(min(m0 + cfg.M, n - 1), ef_st)
-        width = max(cfg.expansion_width, 8)
-        boundary = np.argsort(margin, kind="stable")[:n_stitch]
-        batch = min(cfg.build_batch, n_stitch)
-        g = _prefix_graph(corpus_dev, adj[:n], graph_meta)
-        for lo in range(0, n_stitch, batch):
-            hi = min(lo + batch, n_stitch)
-            bids = boundary[lo:hi]
-            if len(bids) < batch:
-                bids = np.concatenate(
-                    [bids, np.full(batch - len(bids), n, np.int64)])
-            q = vecs[np.minimum(bids, n - 1)]
-            g = g._replace(adj0=adj[:n])
-            bd, bi = beam_search(g, jnp.asarray(q), k=k_st, ef=ef_st,
-                                 max_level=max_level, metric=mode,
-                                 expansion_width=width)
-            # merge beam hits with the node's existing row, re-prune
-            ci = np.concatenate(
-                [np.asarray(bi), np.asarray(adj[np.minimum(bids, n - 1)])],
-                axis=1)
-            cd = np.concatenate(
-                [np.asarray(bd), np.asarray(adj_d[np.minimum(bids, n - 1)])],
-                axis=1)
-            sel_i, sel_d, sel_p = _prune_chunks(corpus_dev, bids, ci, cd,
-                                                m=m0, mode=mode,
-                                                keep_pruned=cfg.keep_pruned,
-                                                chunk=batch)
-            pad_rows = bids >= n
-            sel_i[pad_rows] = PAD
-            sel_d[pad_rows] = INF
-            tgt, src, dd, pp = _edges_both_ways(sel_i, sel_d, sel_p,
-                                                bids.astype(np.int32))
-            adj, adj_d, adj_p = _merge_cap(
-                adj, adj_d, adj_p, jnp.asarray(tgt), jnp.asarray(src),
-                jnp.asarray(dd), jnp.asarray(pp), m=m0)
-            if progress is not None:
-                progress("stitch", hi, n_stitch)
-        logger.debug("bulk coarse: stitched %d boundary nodes", n_stitch)
-
-    adj0 = np.array(adj[:n])
-    adj0_d = np.array(adj_d[:n])
-    t_stitch = time.perf_counter()
+    with tracing.span("build.stitch") as phase:
+        if n_stitch > 0:
+            adj, adj_d, adj_p = _stitch(vecs, corpus_dev, cfg, graph_meta,
+                                        mode, progress, margin, n_stitch,
+                                        adj, adj_d, adj_p)
+        adj0 = np.array(adj[:n])
+        adj0_d = np.array(adj_d[:n])
+    seconds["stitch"] = phase.seconds
+    # seconds per phase, each phase's outputs ready on the device
     return adj0, adj0_d, {"build_clusters": nlist,
                           "build_stitched": n_stitch,
-                          # host-clock seconds per phase (device work synced)
-                          "build_s_candidates": t_cand - t0,
-                          "build_s_prune": t_prune - t_cand,
-                          "build_s_merge": t_merge - t_prune,
-                          "build_s_stitch": t_stitch - t_merge}
+                          **{f"build_s_{k}": v for k, v in seconds.items()}}
+
+
+def _stitch(vecs: np.ndarray, corpus_dev: jax.Array, cfg: HNSWConfig,
+            graph_meta, mode: str, progress: Optional[ProgressFn],
+            margin: np.ndarray, n_stitch: int, adj: jax.Array,
+            adj_d: jax.Array, adj_p: jax.Array
+            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The ``n_stitch`` nodes nearest a cluster boundary re-search the
+    built graph; their rows merge the beam's hits and are re-pruned."""
+    _, max_level, _ = graph_meta
+    n = vecs.shape[0]
+    m0 = cfg.m0
+    ef_st = max(min(cfg.ef_build or STITCH_EF, STITCH_EF), cfg.M)
+    k_st = min(min(m0 + cfg.M, n - 1), ef_st)
+    width = max(cfg.expansion_width, 8)
+    boundary = np.argsort(margin, kind="stable")[:n_stitch]
+    batch = min(cfg.build_batch, n_stitch)
+    g = _prefix_graph(corpus_dev, adj[:n], graph_meta)
+    for lo in range(0, n_stitch, batch):
+        hi = min(lo + batch, n_stitch)
+        bids = boundary[lo:hi]
+        if len(bids) < batch:
+            bids = np.concatenate(
+                [bids, np.full(batch - len(bids), n, np.int64)])
+        q = vecs[np.minimum(bids, n - 1)]
+        g = g._replace(adj0=adj[:n])
+        bd, bi = beam_search(g, jnp.asarray(q), k=k_st, ef=ef_st,
+                             max_level=max_level, metric=mode,
+                             expansion_width=width)
+        # merge beam hits with the node's existing row, re-prune
+        ci = np.concatenate(
+            [np.asarray(bi), np.asarray(adj[np.minimum(bids, n - 1)])],
+            axis=1)
+        cd = np.concatenate(
+            [np.asarray(bd), np.asarray(adj_d[np.minimum(bids, n - 1)])],
+            axis=1)
+        sel_i, sel_d, sel_p = _prune_chunks(corpus_dev, bids, ci, cd,
+                                            m=m0, mode=mode,
+                                            keep_pruned=cfg.keep_pruned,
+                                            chunk=batch)
+        pad_rows = bids >= n
+        sel_i[pad_rows] = PAD
+        sel_d[pad_rows] = INF
+        tgt, src, dd, pp = _edges_both_ways(sel_i, sel_d, sel_p,
+                                            bids.astype(np.int32))
+        adj, adj_d, adj_p = _merge_cap(
+            adj, adj_d, adj_p, jnp.asarray(tgt), jnp.asarray(src),
+            jnp.asarray(dd), jnp.asarray(pp), m=m0)
+        if progress is not None:
+            progress("stitch", hi, n_stitch)
+    logger.debug("bulk coarse: stitched %d boundary nodes", n_stitch)
+    return adj, adj_d, adj_p
 
 
 # ---------------------------------------------------------------------------
@@ -710,13 +725,13 @@ def bulk_build_device(vectors: np.ndarray,
     adj0, adj0_d, info = build_fn(vecs, table, cfg, rng, levels, graph_meta,
                                   dev_metric, progress)
 
-    t_layer0 = time.perf_counter()
-    repaired = _repair_connectivity(vecs, adj0, adj0_d, entry_global,
-                                    dev_metric)
+    with tracing.span("build.repair") as phase:
+        repaired = _repair_connectivity(vecs, adj0, adj0_d, entry_global,
+                                        dev_metric)
     if repaired:
         logger.info("bulk build: reattached %d stranded nodes", repaired)
     info.update({"builder_mode": mode, "build_repaired": repaired,
-                 "build_s_repair": time.perf_counter() - t_layer0})
+                 "build_s_repair": phase.seconds})
 
     return PackedHNSW(config=cfg, vectors=vecs, adj0=adj0,
                       upper_ids=upper_ids, levels=levels,

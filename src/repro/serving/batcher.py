@@ -18,6 +18,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import tracing
+
 
 @dataclasses.dataclass
 class Request:
@@ -26,6 +28,8 @@ class Request:
     future: "Future"
     enqueued_at: float
     extras: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    # the served request this query belongs to (``tracing.REQUEST``)
+    req: Optional[int] = None
     # requests are only co-batched when their extras (filter/ef/...) agree;
     # repr-compare since extras values (Filter trees) aren't hashable
     extras_key: str = ""
@@ -42,6 +46,7 @@ class Future:
         self._ev = threading.Event()
         self._value = None
         self._exc: Optional[BaseException] = None
+        self.batch: Optional[int] = None    # id of the batch that answered
 
     def set(self, value):
         if self._ev.is_set():       # first resolution wins (close() may race
@@ -87,6 +92,11 @@ class RequestBatcher:
         self.batches_served = 0      # guarded-by: _state_lock
         self.requests_served = 0     # guarded-by: _state_lock
         self.carried_requests = 0    # guarded-by: _state_lock
+        # seconds requests sat queued: dispatch time minus enqueued_at,
+        # summed over every dispatched request
+        self.queue_wait_s = 0.0      # guarded-by: _state_lock
+        self.requests_failed = 0     # guarded-by: _state_lock
+        tracing.hook_gc()
         self._thread.start()
 
     def submit(self, query: np.ndarray, k: int, **extras: Any) -> Future:
@@ -102,22 +112,26 @@ class RequestBatcher:
                 raise BatcherClosed()
             fut = Future()
             self._q.put(Request(np.asarray(query, np.float32), k, fut,
-                                time.perf_counter(), dict(extras)))
+                                time.perf_counter(), dict(extras),
+                                req=tracing.REQUEST.get()))
             return fut
 
     @staticmethod
-    def zero_stats() -> Dict[str, int]:
+    def zero_stats() -> Dict[str, Any]:
         """Counter shape for collections whose batcher never started."""
         return {"batches_served": 0, "requests_served": 0,
-                "carried_requests": 0, "queue_depth": 0}
+                "carried_requests": 0, "queue_depth": 0,
+                "queue_wait_s": 0.0, "requests_failed": 0}
 
-    def stats(self) -> Dict[str, int]:
+    def stats(self) -> Dict[str, Any]:
         """Serving observability counters (`/stats` endpoint feed)."""
         with self._state_lock:
             return {"batches_served": self.batches_served,
                     "requests_served": self.requests_served,
                     "carried_requests": self.carried_requests,
-                    "queue_depth": self._q.qsize()}
+                    "queue_depth": self._q.qsize(),
+                    "queue_wait_s": self.queue_wait_s,
+                    "requests_failed": self.requests_failed}
 
     def close(self, timeout: float = 2.0):
         """Stop the worker.  Requests it never got to — queued behind the
@@ -163,29 +177,44 @@ class RequestBatcher:
                     return
                 first, self._carry = self._carry, None
             if first is None:
-                first = self._q.get()
+                with tracing.span("batcher.idle"):
+                    first = self._q.get()
                 if first is None:
                     return
-            batch = [first]
-            deadline = time.perf_counter() + self.max_wait
-            while len(batch) < self.max_batch:
-                left = deadline - time.perf_counter()
-                if left <= 0:
-                    break
-                try:
-                    nxt = self._q.get(timeout=left)
-                except queue.Empty:
-                    break
-                if nxt is None:
-                    with self._state_lock:
-                        self._running = False
-                    break
-                if nxt.extras_key != first.extras_key:
-                    with self._state_lock:  # incompatible: heads next batch
-                        self._carry = nxt
-                        self.carried_requests += 1
-                    break
-                batch.append(nxt)
+            token = tracing.BATCH.set(tracing.next_id())
+            try:
+                self._serve_batch(first)
+            finally:
+                tracing.BATCH.reset(token)
+
+    def _collect(self, first: Request) -> List[Request]:
+        """``first`` and what joins it within max_wait, up to max_batch."""
+        batch = [first]
+        deadline = time.perf_counter() + self.max_wait
+        while len(batch) < self.max_batch:
+            left = deadline - time.perf_counter()
+            if left <= 0:
+                break
+            try:
+                nxt = self._q.get(timeout=left)
+            except queue.Empty:
+                break
+            if nxt is None:
+                with self._state_lock:
+                    self._running = False
+                break
+            if nxt.extras_key != first.extras_key:
+                with self._state_lock:  # incompatible: heads next batch
+                    self._carry = nxt
+                    self.carried_requests += 1
+                break
+            batch.append(nxt)
+        return batch
+
+    def _serve_batch(self, first: Request) -> None:
+        d = ids = error = None
+        with tracing.span("batcher.fill") as fill:
+            batch = self._collect(first)
             try:
                 k = max(r.k for r in batch)
                 queries = np.stack([r.query for r in batch])
@@ -198,24 +227,52 @@ class RequestBatcher:
                 bucket = min(self.max_batch,
                              1 << (len(batch) - 1).bit_length())
                 if bucket > len(batch):
-                    fill = np.broadcast_to(
+                    fill_rows = np.broadcast_to(
                         queries[:1], (bucket - len(batch),) +
                         queries.shape[1:])
-                    queries = np.concatenate([queries, fill])
-                d, ids = self._search(queries, k, **first.extras)
-                d, ids = np.asarray(d)[: len(batch)], \
-                    np.asarray(ids)[: len(batch)]
+                    queries = np.concatenate([queries, fill_rows])
             except Exception as exc:          # surface, don't kill the loop
-                for r in batch:
-                    r.future.set_exception(exc)
-                continue
+                error = exc
+            dispatched = time.perf_counter()
+            wait = sum(dispatched - r.enqueued_at for r in batch)
+            if error is None:
+                args = {"requests": len(batch), "bucket": bucket,
+                        "queue_wait_s": wait}
+                if tracing.enabled():
+                    args["reqs"] = ";".join(str(r.req) for r in batch
+                                            if r.req is not None)
+                fill.set(**args)
+        if error is None:
+            token = tracing.REAL_QUERIES.set(len(batch))
+            try:
+                d, ids = self._search(queries, k, **first.extras)
+            except Exception as exc:          # surface, don't kill the loop
+                error = exc
+            finally:
+                tracing.REAL_QUERIES.reset(token)
+        with tracing.span("batcher.resolve"):
+            if error is None:
+                try:
+                    d, ids = np.asarray(d)[: len(batch)], \
+                        np.asarray(ids)[: len(batch)]
+                except Exception as exc:      # a malformed answer fails
+                    error = exc               # the batch, not the loop
             # count before resolving: a caller reading stats() right after
             # its result arrives must see this batch reflected
             with self._state_lock:
-                self.batches_served += 1
-                self.requests_served += len(batch)
+                self.queue_wait_s += wait
+                if error is None:
+                    self.batches_served += 1
+                    self.requests_served += len(batch)
+                else:
+                    self.requests_failed += len(batch)
+            batch_id = tracing.BATCH.get()
             for i, r in enumerate(batch):
-                r.future.set((d[i, : r.k], ids[i, : r.k]))
+                r.future.batch = batch_id
+                if error is None:
+                    r.future.set((d[i, : r.k], ids[i, : r.k]))
+                else:
+                    r.future.set_exception(error)
 
 
 class QuorumFanout:

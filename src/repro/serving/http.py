@@ -46,11 +46,12 @@ import json
 import re
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 from urllib.parse import parse_qs, unquote
 
 import numpy as np
 
+from .. import tracing
 from ..api import requests as rq
 from .service import QuantixarService, ServiceConfig
 
@@ -210,6 +211,18 @@ def _r_restore(body):
     return _build(rq.Restore, **body)
 
 
+def _request_for(method: str, path: str, body: Dict[str, Any]
+                 ) -> Optional[rq.Request]:
+    """The request of the first route that matches, or None."""
+    for route_method, pattern, builder in _ROUTES:
+        if route_method != method:
+            continue
+        m = pattern.match(path)
+        if m is not None:
+            return builder(body, *[unquote(g) for g in m.groups()])
+    return None
+
+
 class _Handler(BaseHTTPRequestHandler):
     protocol_version = "HTTP/1.1"
     server_version = "quantixar"
@@ -264,56 +277,60 @@ class _Handler(BaseHTTPRequestHandler):
         return body
 
     def _handle(self, method: str) -> None:
-        try:
-            path, _, qs = self.path.partition("?")
-            body = {**_query_params(qs), **self._read_body()}
-            if path == "/v1/rpc" and method == "POST":
-                ok, payload = self._service.dispatch_dict(body)
-                code = 200 if ok else ERROR_STATUS.get(
-                    payload.get("code", rq.INTERNAL), 500)
-                return self._reply(code, ok, payload)
-            for route_method, pattern, builder in _ROUTES:
-                if route_method != method:
-                    continue
-                m = pattern.match(path)
-                if m is None:
-                    continue
-                groups = [unquote(g) for g in m.groups()]
-                request = builder(body, *groups)
+        with tracing.request():
+            try:
+                with tracing.span("wire.decode"):
+                    path, _, qs = self.path.partition("?")
+                    body = {**_query_params(qs), **self._read_body()}
+                    rpc = path == "/v1/rpc" and method == "POST"
+                    request = None if rpc else _request_for(method, path,
+                                                            body)
+                if rpc:
+                    ok, payload = self._service.dispatch_dict(body)
+                    code = 200 if ok else ERROR_STATUS.get(
+                        payload.get("code", rq.INTERNAL), 500)
+                    return self._reply(code, ok, payload)
+                if request is None:
+                    info = rq.ErrorInfo(rq.NOT_FOUND,
+                                        f"no route {method} {path}")
+                    return self._reply(404, False, info.to_dict())
                 out = self._service.dispatch(request)
                 if isinstance(out, rq.ErrorInfo):
                     return self._reply(ERROR_STATUS.get(out.code, 500),
                                        False, out.to_dict())
-                return self._reply(200, True, out.to_dict())
-            info = rq.ErrorInfo(rq.NOT_FOUND,
-                                f"no route {method} {path}")
-            return self._reply(404, False, info.to_dict())
-        except rq.ApiError as exc:
-            return self._reply(ERROR_STATUS.get(exc.code, 500), False,
-                               exc.info.to_dict())
-        except Exception as exc:             # noqa: BLE001 — no tracebacks
-            info = rq.ErrorInfo(rq.INTERNAL,
-                                f"{type(exc).__name__}: {exc}")
-            return self._reply(500, False, info.to_dict())
+                return self._reply(200, True, out)
+            except rq.ApiError as exc:
+                return self._reply(ERROR_STATUS.get(exc.code, 500), False,
+                                   exc.info.to_dict())
+            except Exception as exc:         # noqa: BLE001 — no tracebacks
+                info = rq.ErrorInfo(rq.INTERNAL,
+                                    f"{type(exc).__name__}: {exc}")
+                return self._reply(500, False, info.to_dict())
 
-    def _reply(self, status: int, ok: bool, payload: Dict[str, Any]) -> None:
-        envelope = {"ok": ok, ("result" if ok else "error"): payload}
-        try:
-            data = json.dumps(envelope, default=_json_default).encode("utf-8")
-        except TypeError as exc:
-            status, data = 500, json.dumps({
-                "ok": False,
-                "error": rq.ErrorInfo(
-                    rq.INTERNAL, f"unserializable response: {exc}").to_dict(),
-            }).encode("utf-8")
-        try:
-            self.send_response(status)
-            self.send_header("Content-Type", "application/json")
-            self.send_header("Content-Length", str(len(data)))
-            self.end_headers()
-            self.wfile.write(data)
-        except (BrokenPipeError, ConnectionResetError):
-            pass                             # client went away mid-reply
+    def _reply(self, status: int, ok: bool,
+               payload: Union[Dict[str, Any], rq.Response]) -> None:
+        with tracing.span("wire.encode"):
+            if isinstance(payload, rq.Response):   # its dict is encoding too
+                payload = payload.to_dict()
+            envelope = {"ok": ok, ("result" if ok else "error"): payload}
+            try:
+                data = json.dumps(envelope,
+                                  default=_json_default).encode("utf-8")
+            except TypeError as exc:
+                status, data = 500, json.dumps({
+                    "ok": False,
+                    "error": rq.ErrorInfo(
+                        rq.INTERNAL,
+                        f"unserializable response: {exc}").to_dict(),
+                }).encode("utf-8")
+            try:
+                self.send_response(status)
+                self.send_header("Content-Type", "application/json")
+                self.send_header("Content-Length", str(len(data)))
+                self.end_headers()
+                self.wfile.write(data)
+            except (BrokenPipeError, ConnectionResetError):
+                pass                         # client went away mid-reply
 
 
 class _Server(ThreadingHTTPServer):
@@ -339,6 +356,7 @@ class QuantixarHTTPServer:
         self._httpd.verbose = verbose
         self._thread: Optional[threading.Thread] = None
         self._serving = False
+        tracing.hook_gc()
 
     @property
     def host(self) -> str:

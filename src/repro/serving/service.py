@@ -26,6 +26,7 @@ from typing import Any, Callable, Dict, Optional, Tuple, Type, Union
 
 import numpy as np
 
+from .. import tracing
 from ..api import requests as rq
 from ..api.collection import CollectionClosed, QueryRetriesExhausted
 from ..api.database import Database
@@ -198,52 +199,58 @@ class QuantixarService:
     def _search(self, req: rq.Search) -> rq.SearchResult:
         col = self._col(req.collection)
         timeout = self.config.query_timeout_s
-        if req.plan is not None:
-            # full declarative plan: validate/execute through the one plan
-            # path (trivial plans still coalesce in the RequestBatcher)
-            plan = plan_from_dict(req.plan)
+        with tracing.span("api.plan"):
+            plan = query = None
+            if req.plan is not None:
+                # full declarative plan: validate/execute through the one
+                # plan path (trivial plans still coalesce in the
+                # RequestBatcher)
+                plan = plan_from_dict(req.plan)
+                batched = plan.batched
+            else:
+                if req.vector is None and req.text is None:
+                    raise rq.error_to_exception(rq.ErrorInfo(
+                        rq.INVALID_ARGUMENT,
+                        "search needs either 'vector', 'text', or 'plan'"))
+                vector = None
+                if req.vector is not None:
+                    vector = np.asarray(req.vector, dtype=np.float32)
+                flt = rq.filter_from_dict(req.filter)
+                query = col.query(vector).top_k(req.k)
+                if req.text is not None:
+                    # keyword leg: alone -> pure sparse plan; with a vector
+                    # -> hybrid RRF plan, same compile as Query.text()
+                    query = query.text(req.text, field=req.text_field)
+                if flt is not None:
+                    query = query.filter(flt)
+                if req.ef is not None:
+                    query = query.ef(req.ef)
+                if req.rescore is not None:
+                    query = query.rescore(req.rescore)
+                if req.expansion_width is not None:
+                    query = query.expansion_width(req.expansion_width)
+                if req.include_vector:
+                    query = query.include("vector")
+                batched = vector is not None and vector.ndim == 2
+        if plan is not None:
             out = col.execute_plan(plan, include_vector=req.include_vector,
                                    timeout=timeout, explain=req.explain)
-            batched = plan.batched
         else:
-            if req.vector is None and req.text is None:
-                raise rq.error_to_exception(rq.ErrorInfo(
-                    rq.INVALID_ARGUMENT,
-                    "search needs either 'vector', 'text', or 'plan'"))
-            vector = None
-            if req.vector is not None:
-                vector = np.asarray(req.vector, dtype=np.float32)
-            flt = rq.filter_from_dict(req.filter)
-            query = col.query(vector).top_k(req.k)
-            if req.text is not None:
-                # keyword leg: alone -> pure sparse plan; with a vector ->
-                # hybrid RRF plan, same compile as the fluent Query.text()
-                query = query.text(req.text, field=req.text_field)
-            if flt is not None:
-                query = query.filter(flt)
-            if req.ef is not None:
-                query = query.ef(req.ef)
-            if req.rescore is not None:
-                query = query.rescore(req.rescore)
-            if req.expansion_width is not None:
-                query = query.expansion_width(req.expansion_width)
-            if req.include_vector:
-                query = query.include("vector")
             # the fluent builder compiles to a trivial plan: 1-D requests
             # coalesce through the RequestBatcher, 2-D run as one batch
             out = (query.explain(timeout=timeout) if req.explain
                    else query.run(timeout=timeout))
-            batched = vector is not None and vector.ndim == 2
-        explain = None
-        hits = out
-        if req.explain:
-            hits, explain = out.hits, out.to_dict()
-        if not batched:
-            return rq.SearchResult(hits=[_hit_to_dict(h) for h in hits],
-                                   explain=explain)
-        return rq.SearchResult(
-            hits=[[_hit_to_dict(h) for h in row] for row in hits],
-            batched=True, explain=explain)
+        with tracing.span("api.hits"):
+            explain = None
+            hits = out
+            if req.explain:
+                hits, explain = out.hits, out.to_dict()
+            if not batched:
+                return rq.SearchResult(
+                    hits=[_hit_to_dict(h) for h in hits], explain=explain)
+            return rq.SearchResult(
+                hits=[[_hit_to_dict(h) for h in row] for row in hits],
+                batched=True, explain=explain)
 
     def _count(self, req: rq.Count) -> rq.CountResult:
         col = self._col(req.collection)

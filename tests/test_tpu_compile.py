@@ -104,9 +104,10 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
 @pytest.mark.parametrize("metric", ["dot", "adc", "hamming"])
 def test_served_search_compiles_for_v5e(metric, one_chip,
                                         no_persistent_cache, monkeypatch):
-    """The whole vmapped HNSW search program, 32 queries, ef=128.  The
-    process's backend is the CPU, so the test steers the kernel dispatch
-    to the TPU branch (compiled kernels) itself."""
+    """The whole vmapped HNSW search program, 32 queries, ef=128, with the
+    trip counters the engine fetches beside the answers.  The process's
+    backend is the CPU, so the test steers the kernel dispatch to the TPU
+    branch (compiled kernels) itself."""
     from repro.kernels import ops
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     search.clear_cache()
@@ -127,7 +128,7 @@ def test_served_search_compiles_for_v5e(metric, one_chip,
                   entry_global=s((), jnp.int32), codes=codes)
     compiled = search.lower(g, s((32, D), jnp.float32), k=10, ef=128,
                             max_level=4, metric=metric, expansion_width=4,
-                            q_codes=q_codes).compile()
+                            q_codes=q_codes, with_iters=True).compile()
     search.clear_cache()
     # the entry point's one-row call and the per-step block call
     assert compiled.as_text().count("tpu_custom_call") >= 2
