@@ -101,7 +101,9 @@ class QuantixarEngine:
         self._packed: Optional[PackedHNSW] = None
         self._device_graph = None                  # (HNSWGraph, max_level, metric)
         self._ivf: Optional[IVFIndex] = None
-        self._ivf_effective: Optional[np.ndarray] = None
+        # the (N, D) matrix IVF lists index into, as a store so that its
+        # device copy is kept (and dropped with it on a rebuild)
+        self._ivf_effective: Optional[ChunkedArray] = None
         self._dirty = True          # no usable sealed segment yet: build first
         self._sealed_n = 0          # rows covered by the sealed segment
         self._delta: Optional[DeltaSegment] = None  # exists once sealed
@@ -112,6 +114,12 @@ class QuantixarEngine:
         self.hnsw_trips = 0         # layer-0 traversal trips, real queries
         self.hnsw_queries = 0       # real queries the traversal answered
         self.flat_fallbacks = 0     # masked beams that under-delivered
+        # device copies of whole-corpus stores (raw vectors, codes, IVF
+        # matrix): passes served from a copy, whole-store uploads, uploads
+        # of appended rows only
+        self.resident_hits = 0
+        self.resident_uploads = 0
+        self.resident_appends = 0
         # observability for the segmented write path: a post-build add() must
         # bump none of these; seal() bumps seal/index, never quantizer_trains
         self.index_builds = 0       # HNSW-graph / IVF-list constructions
@@ -278,7 +286,7 @@ class QuantixarEngine:
                     cfg.ivf, metric="l2" if eff_metric != "cosine" else "cosine"))
                 self._ivf.train(jnp.asarray(raw), seed=seed)
             self._ivf.build_lists(jnp.asarray(raw))
-            self._ivf_effective = eff
+            self._ivf_effective = ChunkedArray([eff])
         else:
             self._packed = None
             self._device_graph = None
@@ -421,6 +429,19 @@ class QuantixarEngine:
         self.h2d_bytes += x.nbytes
         return out
 
+    def _resident(self, store: ChunkedArray) -> jax.Array:
+        """The device copy of a whole-corpus store for one pass: uploaded
+        (through `_h2d`) on first use, extended by appended rows only, and
+        kept after the pass."""
+        out, how = store.on_device(self._h2d)
+        if how == "hit":
+            self.resident_hits += 1
+        elif how == "upload":
+            self.resident_uploads += 1
+        else:
+            self.resident_appends += 1
+        return out
+
     @staticmethod
     def _real(n: int) -> int:
         """Real queries of an ``n``-row pass: a served batch is padded."""
@@ -438,7 +459,7 @@ class QuantixarEngine:
         mask_j = None if mask is None else self._h2d(mask)
         q = self._h2d(queries)
         if cfg.quantization in ("pq", "bq"):
-            codes = self._h2d(self._codes)
+            codes = self._resident(self._code_chunks)
             with self._device("flat", len(queries)):
                 if cfg.quantization == "pq":
                     lut = pq_mod.build_adc_lut(
@@ -452,13 +473,13 @@ class QuantixarEngine:
                     d = jnp.where(mask_j[None, :], d, jnp.inf)
                 neg_top, idx = jax.device_get(
                     jax.lax.top_k(-d, min(k, d.shape[1])))
-                del q, codes, mask_j, d     # freed inside the pass's span
+                del q, mask_j, d            # freed inside the pass's span
             return -neg_top, idx.astype(np.int32)
-        corpus = self._h2d(self.vectors)
+        corpus = self._resident(self._vectors)
         with self._device("flat", len(queries)):
             out = jax.device_get(flat_search(q, corpus, min(k, self._n),
                                              metric=cfg.metric, mask=mask_j))
-            del q, corpus, mask_j       # the pass's uploads are freed here
+            del q, mask_j               # the pass's uploads are freed here
         return out
 
     def _hnsw_pass(self, queries, k, ef, mask, expansion_width=None):
@@ -532,7 +553,7 @@ class QuantixarEngine:
 
     def _ivf_pass(self, queries, k, mask):
         """Probe the sealed IVF lists only (delta rows merge separately)."""
-        eff, q = self._h2d(self._ivf_effective), self._h2d(queries)
+        eff, q = self._resident(self._ivf_effective), self._h2d(queries)
         with self._device("ivf", len(queries)):
             d, ids = jax.device_get(self._ivf.search(eff, q, k))
         with tracing.span("engine.post"):
@@ -723,7 +744,8 @@ class QuantixarEngine:
                 eng._ivf = IVFIndex(config.ivf)
                 eff = eng.vectors
             eng._ivf.load_state_dict(ivf_state)
-            eng._ivf_effective = eff[:sealed_n]   # lists cover sealed rows only
+            # lists cover sealed rows only
+            eng._ivf_effective = ChunkedArray([eff[:sealed_n]])
             eng._dirty = False
         hnsw_state = {k[5:]: v for k, v in state.items()
                       if k.startswith("hnsw.")}
@@ -758,6 +780,9 @@ class QuantixarEngine:
                "hnsw_trips": self.hnsw_trips,
                "hnsw_queries": self.hnsw_queries,
                "flat_fallbacks": self.flat_fallbacks,
+               "resident_hits": self.resident_hits,
+               "resident_uploads": self.resident_uploads,
+               "resident_appends": self.resident_appends,
                "sealed_rows": self._sealed_n,
                "delta_rows": self.delta_rows,
                "index_builds": self.index_builds,

@@ -24,8 +24,10 @@ This module owns the delta-side bookkeeping:
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Tuple
+from typing import Callable, List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 
@@ -57,18 +59,24 @@ class ChunkedArray:
     Every write-path buffer in the engine has this access pattern (raw
     vectors, code matrices, the delta's copies of both): O(batch) appends,
     occasional whole-array reads.  `view()` collapses the chunk list once
-    and caches the result until the next append.
+    and caches the result until the next append.  `on_device()` keeps a
+    device copy of the whole store across searches; appends are the only
+    write, so the copy is stale exactly when the store holds more rows.
     """
 
     def __init__(self, chunks: Optional[List[np.ndarray]] = None):
         self._chunks: List[np.ndarray] = \
             [np.asarray(c) for c in (chunks or [])]
+        self._rows = sum(len(c) for c in self._chunks)
+        self._device: Optional[jax.Array] = None   # copy of the first rows
 
     def __bool__(self) -> bool:
         return bool(self._chunks)
 
     def append(self, arr: np.ndarray) -> None:
-        self._chunks.append(np.asarray(arr))
+        arr = np.asarray(arr)
+        self._chunks.append(arr)
+        self._rows += len(arr)
 
     def view(self) -> Optional[np.ndarray]:
         """The concatenated array, or None when nothing was appended."""
@@ -77,6 +85,32 @@ class ChunkedArray:
         if len(self._chunks) > 1:
             self._chunks = [np.concatenate(self._chunks, axis=0)]
         return self._chunks[0]
+
+    def _rows_from(self, start: int) -> np.ndarray:
+        """Rows ``start:`` without collapsing the chunk list."""
+        parts, pos = [], 0
+        for c in self._chunks:
+            if pos + len(c) > start:
+                parts.append(c[max(0, start - pos):])
+            pos += len(c)
+        return parts[0] if len(parts) == 1 else np.concatenate(parts, axis=0)
+
+    def on_device(self, upload: Callable[[np.ndarray], jax.Array]
+                  ) -> Tuple[jax.Array, str]:
+        """Device copy of the whole (non-empty) store, kept across calls.
+        ``upload`` moves one host array to the device.  The first call
+        uploads every row; a later one uploads only the rows appended since
+        and joins them to the copy on the device.  Returns (copy, what the
+        call did: "hit", "upload" or "append")."""
+        if self._device is None:
+            self._device = upload(self.view())
+            return self._device, "upload"
+        held = int(self._device.shape[0])
+        if held == self._rows:
+            return self._device, "hit"
+        self._device = jnp.concatenate(
+            [self._device, upload(self._rows_from(held))], axis=0)
+        return self._device, "append"
 
 
 class DeltaSegment:

@@ -203,3 +203,127 @@ class TestIVF:
         eng2 = QuantixarEngine.from_state_dict(eng.config, eng.state_dict())
         d2, i2 = eng2.search(queries, 10)
         assert (i1 == i2).all()
+
+
+# kwargs of `_engine`, the filter of every search, and whether an add()
+# grows the store the pass reads (IVF lists cover sealed rows only: its
+# new rows ride the delta segment's own device matrix)
+RESIDENT = {
+    "flat": (dict(index="flat"), None, True),
+    "flat-pq": (dict(index="flat", quantization="pq"), None, True),
+    "flat-bq": (dict(index="flat", quantization="bq"), None, True),
+    "ivf": (dict(index="ivf"), None, False),
+    "hnsw-filtered": (dict(index="hnsw"),
+                      And([Predicate("cat", "eq", 1),
+                           Predicate("score", "lt", 0.2)]), True),
+}
+NEW_ROWS = 8
+
+
+def _store(eng):
+    """The whole-corpus store whose device copy the engine's pass reads."""
+    if eng.config.index == "ivf":
+        return eng._ivf_effective
+    if eng.config.quantization != "none":
+        return eng._code_chunks
+    return eng._vectors
+
+
+def _resident(eng):
+    return tuple(eng.stats()[f"resident_{k}"]
+                 for k in ("uploads", "appends", "hits"))
+
+
+class TestResidentCopy:
+    """Whole-corpus stores stay on the device between passes."""
+
+    @pytest.mark.parametrize("case", RESIDENT)
+    def test_second_search_uploads_only_queries(self, corpus, queries,
+                                                meta, case):
+        kw, flt, _ = RESIDENT[case]
+        eng = _engine(corpus, meta, **kw)
+        h0 = eng.h2d_bytes
+        d1, i1 = eng.search(queries, 10, flt=flt, rescore=False)
+        first = eng.h2d_bytes - h0
+        d2, i2 = eng.search(queries, 10, flt=flt, rescore=False)
+        second = eng.h2d_bytes - h0 - first
+        assert second == queries.nbytes + (0 if flt is None else N)
+        assert first == second + _store(eng).view().nbytes
+        assert _resident(eng) == (1, 0, 1)
+        assert np.array_equal(d1, d2) and np.array_equal(i1, i2)
+
+    @pytest.mark.parametrize("case", RESIDENT)
+    def test_added_rows_are_found_and_uploaded_alone(self, corpus, queries,
+                                                     meta, case):
+        kw, flt, grows = RESIDENT[case]
+        eng = _engine(corpus, meta, **kw)
+        eng.search(queries, 10, flt=flt)
+        held = _store(eng).view().nbytes
+        new = gaussian_mixture(NEW_ROWS, DIM, n_clusters=4, scale=0.2,
+                               seed=7)
+        eng.add(new, [{"cat": 1, "score": 0.0}] * NEW_ROWS)
+        h0 = eng.h2d_bytes
+        d, ids = eng.search(new, 10, flt=flt)
+        after_add = eng.h2d_bytes - h0
+        eng.search(new, 10, flt=flt)
+        steady = eng.h2d_bytes - h0 - after_add
+        grown = _store(eng).view().nbytes - held
+        assert (grown > 0) == grows
+        delta = 0 if grows else eng._delta_cache[2].nbytes
+        assert after_add - steady == grown + delta
+        assert _resident(eng) == (1, int(grows), 2 - int(grows))
+        assert np.array_equal(ids[:, 0], np.arange(N, N + NEW_ROWS))
+        # an engine restored from the same state holds no copy yet
+        fresh = QuantixarEngine.from_state_dict(eng.config, eng.state_dict())
+        d2, ids2 = fresh.search(new, 10, flt=flt)
+        assert np.array_equal(d, d2) and np.array_equal(ids, ids2)
+
+
+def _collection(kw):
+    from repro.api import Database, VectorField
+    return Database().create_collection(
+        name="c", vector=VectorField(dim=DIM, pq=PQConfig(m=8, k=32, iters=8),
+                                     **kw))
+
+
+def _rebuilt(op, kw, corpus, meta, queries):
+    """An engine taken through ``op`` after searches that made and
+    extended the device copies of its stores."""
+    new = gaussian_mixture(NEW_ROWS, DIM, n_clusters=4, scale=0.2, seed=7)
+    if op == "compact":
+        col = _collection(kw)
+        ids = [str(i) for i in range(N)]
+        col.upsert(ids, corpus)
+        col.seal()
+        col.search(queries, 10)
+        col.upsert([f"n{i}" for i in range(NEW_ROWS)], new)
+        col.search(queries, 10)
+        col.delete(ids[::7])
+        col.compact()                  # a new engine over the live rows
+        return col._engine
+    eng = _engine(corpus, meta, **kw)
+    eng.search(queries, 10)
+    eng.add(new, [None] * NEW_ROWS)
+    eng.search(queries, 10)
+    if op == "build":
+        eng.build()                    # retrains codes and index
+    elif op == "seal":
+        eng.seal()                     # rebuilds the index only
+    return eng
+
+
+@pytest.mark.parametrize("case", ["flat", "flat-pq", "ivf-pq"])
+@pytest.mark.parametrize("op", ["build", "seal", "compact", "state_dict"])
+def test_rebuilt_engine_answers_like_a_fresh_one(corpus, queries, meta,
+                                                 case, op):
+    """A copy made before a rebuild, a compaction or a save never answers
+    for the stores that replaced it: the engine answers exactly as one
+    restored from its state, which holds no copy."""
+    index, _, quant = case.partition("-")
+    eng = _rebuilt(op, dict(index=index, quantization=quant or "none"),
+                   corpus, meta, queries)
+    fresh = QuantixarEngine.from_state_dict(eng.config, eng.state_dict())
+    d1, i1 = eng.search(queries, 10)
+    d2, i2 = fresh.search(queries, 10)
+    assert np.array_equal(d1, d2) and np.array_equal(i1, i2)
+    assert fresh.resident_uploads == 1 and fresh.resident_hits == 0

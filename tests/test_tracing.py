@@ -196,18 +196,19 @@ def test_h2d_bytes_are_the_arrays_uploaded(served):
             uploads[s[4]["batch"]].append(s[4]["bytes"])
     filtered = {s[4]["batch"] for s in _by_name(spans, "engine.filter")}
     assert len(filtered) == 1
+    flat_batches = sorted(b for b in fills if line_of[b] in flat_line)
     for batch, fill in fills.items():
         want = [fill["bucket"] * DIM * 4]
-        if line_of[batch] in flat_line:
-            want.append(N * DIM * 4)
-            if batch in filtered:
-                want.append(N)          # the row mask, one byte a row
+        if batch == flat_batches[0]:
+            want.append(N * DIM * 4)    # the corpus, once: it stays aboard
+        if batch in filtered:
+            want.append(N)              # the row mask, one byte a row
         assert sorted(uploads[batch]) == sorted(want)
     served_bytes = sum(s[4]["bytes"] for s in _by_name(spans, "engine.h2d")
                        if "batch" in s[4])
     explained = sum(s[4]["bytes"] for s in _by_name(spans, "engine.h2d")
                     if "batch" not in s[4])
-    assert explained == DIM * 4 + N * DIM * 4
+    assert explained == DIM * 4
     assert (counters["flat"]["h2d_bytes"] + counters["hnsw"]["h2d_bytes"]
             == served_bytes + explained)
 
